@@ -5,7 +5,10 @@ microbenches + the roofline report.
                                            [--seed N]
 
 Prints ``name,us_per_call,derived`` CSV lines (harness contract) and
-writes full payloads to experiments/bench/*.json.  ``--seed`` threads
+writes full payloads to experiments/bench/*.json.  A benchmark that
+raises prints an ``ERROR:`` line, the others still run, and the exit
+code is 1.  JAX's persistent compile cache is on
+(``repro.launch.compile_cache``).  ``--seed`` threads
 through the serving benchmarks (continuous_vs_batch,
 prefill_interference) so the recorded JSONs are deterministic and
 reproducible for any seed.
@@ -17,12 +20,16 @@ import argparse
 import time
 import traceback
 
+from repro.launch import compile_cache
+
 from . import (chaos_failover, common, continuous_vs_batch, kernel_bench,
                paper_tables, prefill_interference, prefix_cache,
                roofline_report, router_policies, slo_calibration)
 
 
-def run_paper_tables(only=None):
+def run_paper_tables(only=None) -> int:
+    """Run the paper tables; returns how many raised."""
+    errors = 0
     for name, fn in paper_tables.ALL.items():
         if only and only != name:
             continue
@@ -32,9 +39,11 @@ def run_paper_tables(only=None):
         except Exception as e:            # noqa: BLE001
             traceback.print_exc()
             common.emit(name, time.time() - t0, f"ERROR:{e}")
+            errors += 1
             continue
         common.save(name, payload)
         common.emit(name, time.time() - t0, derived)
+    return errors
 
 
 def run_kernels(only=None):
@@ -136,12 +145,13 @@ def main(argv=None):
         out = common.summarize()
         print(f"BENCH_SUMMARY.json: {out['n_benchmarks']} benchmarks")
         return 0
+    compile_cache.enable()
     print("name,us_per_call,derived")
-    run_paper_tables(args.only)
+    errors = run_paper_tables(args.only)
     run_kernels(args.only)
     run_continuous(args.only, seed=args.seed)
     run_roofline(args.only)
-    return 0
+    return 1 if errors else 0
 
 
 if __name__ == "__main__":

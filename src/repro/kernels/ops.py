@@ -28,6 +28,10 @@ from . import (chunked_prefill_attention as _cpa,
 
 
 def _default_interpret() -> bool:
+    """The one place that decides whether a Pallas kernel runs compiled
+    (on TPU) or in interpret mode (elsewhere).  The wrappers below and
+    the model's kernel sites (models/transformer.py) call it at trace
+    time, so a compile for a described TPU steers it here."""
     return jax.default_backend() != "tpu"
 
 

@@ -13,17 +13,6 @@ import math
 import jax
 
 
-def _axis_type_kwargs(n_axes: int) -> dict:
-    """Version-compat shim: jax.sharding.AxisType (and the axis_types
-    kwarg of jax.make_mesh) only exist on newer jax releases.  Older
-    versions behave as Auto everywhere, so omitting the kwarg is
-    semantically identical."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return {}
-    return {"axis_types": (axis_type.Auto,) * n_axes}
-
-
 def make_production_mesh(*, multi_pod: bool = False):
     """16x16 = 256 chips per pod; 2 pods = 512 chips multi-pod."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
@@ -37,7 +26,7 @@ def make_production_mesh(*, multi_pod: bool = False):
             f"device_count=512)")
     return jax.make_mesh(
         shape, axes, devices=jax.devices()[:ndev],
-        **_axis_type_kwargs(len(axes)))
+        axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_mesh(shape, axes):
@@ -45,7 +34,7 @@ def make_mesh(shape, axes):
     ndev = math.prod(shape)
     return jax.make_mesh(
         tuple(shape), tuple(axes), devices=jax.devices()[:ndev],
-        **_axis_type_kwargs(len(axes)))
+        axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def replica_groups(R: int, devices=None):

@@ -144,23 +144,7 @@ def _axes_tuple(r):
     return (r,) if isinstance(r, str) else tuple(r)
 
 
-def _shard_map_fn():
-    """Version shim: jax.shard_map on new releases; the experimental
-    module (whose replication-check kwarg is `check_rep`, not
-    `check_vma`) on older ones.  Local imports keep the module
-    importable before jax backend init."""
-    import functools
-    try:
-        from jax import shard_map as sm
-        return functools.partial(sm, check_vma=False)
-    except ImportError:
-        from jax.experimental.shard_map import shard_map as sm
-        return functools.partial(sm, check_rep=False)
-
-
 def apply_moe_ep(params: dict, x: Array, cfg, policy) -> tuple[Array, dict]:
-    shard_map = _shard_map_fn()  # local: keep module importable early
-
     mesh = policy.mesh
     B, S, D = x.shape
     E, K = cfg.num_experts, cfg.experts_per_token
@@ -353,10 +337,11 @@ def apply_moe_ep(params: dict, x: Array, cfg, policy) -> tuple[Array, dict]:
         }
         return out, aux
 
-    fn = shard_map(
+    fn = jax.shard_map(
         f, mesh=mesh,
         in_specs=(x_spec, router_spec, wg_spec, wg_spec, wd_spec),
-        out_specs=(x_spec, {"moe_aux_loss": P(), "moe_drop_frac": P()}))
+        out_specs=(x_spec, {"moe_aux_loss": P(), "moe_drop_frac": P()}),
+        check_vma=False)
     out, aux = fn(x, params["router"], params["w_gate"], params["w_up"],
                   params["w_down"])
     if cfg.num_shared_experts:

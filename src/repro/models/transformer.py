@@ -24,6 +24,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from repro.kernels import chunked_prefill_attention as cpa_kernel
+from repro.kernels import ops as kernel_ops
 from repro.kernels import paged_decode_attention as pfd_kernel
 from repro.kernels import ragged_chunked_prefill as rcp_kernel
 from repro.kvcache import paged as paged_lib
@@ -212,9 +213,10 @@ def _attn_decode_paged(p, x, pages_k, pages_v, pos, tables, cfg,
     ``paged_decode_attention`` kernel, which streams pages through VMEM
     via scalar-prefetch block-table indirection (the production TPU
     path); the default jnp path gathers a transient contiguous view —
-    exact, but O(slots * max_len) scratch per layer.  On non-TPU
-    backends the kernel body runs in interpret mode (correct, slow) —
-    the engine auto-selects per backend (``generate.make_paged_decode_fn``).
+    exact, but O(slots * max_len) scratch per layer.  Off TPU the
+    kernel body runs in interpret mode (correct, slow;
+    ``kernels.ops._default_interpret`` decides) — the engine
+    auto-selects per backend (``generate.make_paged_decode_fn``).
     """
     h = rms_norm(x, p["ln1"], cfg.norm_eps)
     q, k, v = layers.attention_qkv(p["attn"], h, pos[..., None],
@@ -224,7 +226,7 @@ def _attn_decode_paged(p, x, pages_k, pages_v, pos, tables, cfg,
     if use_pallas:
         attn = pfd_kernel.paged_flash_decode_attention(
             q[:, 0], new_k, new_v, tables, pos + 1,
-            interpret=jax.default_backend() != "tpu")[:, None]
+            interpret=kernel_ops._default_interpret())[:, None]
     else:
         k_seq = paged_lib.gather_tokens(new_k, tables)  # (B, nb*bs, KV, D)
         v_seq = paged_lib.gather_tokens(new_v, tables)
@@ -262,7 +264,7 @@ def _attn_chunk_paged(p, x, pages_k, pages_v, positions, table_row, cfg,
     if use_pallas:
         attn = cpa_kernel.chunked_prefill_attention(
             q, new_k, new_v, table_row[None, :], positions[:1],
-            interpret=jax.default_backend() != "tpu")
+            interpret=kernel_ops._default_interpret())
     else:
         k_seq = paged_lib.gather_tokens(new_k, table_row[None, :])
         v_seq = paged_lib.gather_tokens(new_v, table_row[None, :])
@@ -322,7 +324,7 @@ def _attn_chunks_paged(p, x, pages_k, pages_v, ctx, cfg):
                        axis=0).reshape((C, Tp) + v.shape[2:])
         av, new_k, new_v = rcp_kernel.ragged_chunked_prefill(
             qv, knv, vnv, pages_k, pages_v, tables, meta,
-            interpret=jax.default_backend() != "tpu")
+            interpret=kernel_ops._default_interpret())
     else:
         new_k = paged_lib.scatter_packed(pages_k, k[0], tables,
                                          token_chunk, positions, valid)

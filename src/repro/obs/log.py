@@ -1,10 +1,9 @@
 """Rate-limited warnings with countable fallback events.
 
-The serving stack degrades silently in two places: ``use_pallas=None``
-auto-detection falls back to the jnp kernel paths off-TPU, and AOT
-warmup failure degrades to jit-on-first-call.  Both used to be ad-hoc
-one-shot ``logger.warning`` patterns — visible once in stderr, then
-gone, and never countable.  This module centralizes the pattern:
+The serving stack degrades in one place: ``use_pallas=None``
+auto-detection falls back to the jnp kernel paths off-TPU.  That used
+to be an ad-hoc one-shot ``logger.warning`` — visible once in stderr,
+then gone, and never countable.  This module centralizes the pattern:
 
   * each degradation site calls ``warn_once(logger, key, msg, ...)``;
   * the FIRST occurrence per key logs at WARNING; repeats within
@@ -91,7 +90,6 @@ class RateLimitedLogger:
 
 #: process-wide fallback ledger for the serving stack.  Keys in use:
 #:   "jnp-fallback"  — use_pallas auto-detection fell back off-TPU
-#:   "aot-warmup"    — AOT warmup failed; degraded to jit-on-first-call
 FALLBACKS = RateLimitedLogger()
 
 #: active scoped ledgers, innermost last (``scope``) — each engine

@@ -68,7 +68,6 @@ and the jitted prefill/decode executables are reused across batches.
 from __future__ import annotations
 
 import dataclasses
-import logging
 import time
 from typing import Dict, List, Optional, Sequence
 
@@ -96,8 +95,6 @@ from . import generate
 from .faults import shed_pass
 from .pipeline import CompletionWorker
 
-logger = logging.getLogger(__name__)
-
 EOS_ID = 1
 # max_len headroom past input_bucket + max_new_tokens.  It doubles as
 # the multi-step decode window's OVERHANG budget: with readback in
@@ -106,6 +103,17 @@ EOS_ID = 1
 # own ring (contiguous) / its table's clamp range (paged) — hence the
 # constructor's ``decode_steps - 1 <= _MAX_LEN_SLACK`` validation.
 _MAX_LEN_SLACK = 8
+
+
+def params_device(params):
+    """The one device an engine's parameters live on.  The engine puts
+    its KV pool, block tables and compiled executables there too, so R
+    replicas in one process each stay on the device their params were
+    committed to.  Uncommitted params resolve to the default device."""
+    devs = {d for leaf in jax.tree.leaves(params) for d in leaf.devices()}
+    if len(devs) > 1:
+        raise ValueError(f"engine params span several devices: {devs}")
+    return devs.pop() if devs else jax.devices()[0]
 
 
 def hash_tokenize(text: str, vocab_size: int, max_len: int) -> List[int]:
@@ -323,16 +331,23 @@ class ServingEngine:
                         generate.make_ragged_prefill_fn(cfg, use_pallas)
                 if prefix_cache:
                     self._copy_block = generate.make_copy_block_fn(cfg)
+        # the device the params live on: the pool, the tables and every
+        # executable follow it (serve runs under jax.default_device)
+        self.device = params_device(params)
         # AOT warm keys: the factory memo shares JitExecutables across
         # same-cfg engines, so every key carries the dims that fix this
-        # engine's array shapes — two engines with identical dims share
-        # warmed executables; differing dims never collide.
+        # engine's array shapes and the device it was compiled for — two
+        # engines with identical dims on one device share warmed
+        # executables; differing dims or devices never collide.
         self._aot_dims = (self.num_slots, self.input_bucket, self.max_len,
-                          self.kv, self.kv_num_blocks, self.kv_block_size)
+                          self.kv, self.kv_num_blocks, self.kv_block_size,
+                          self.device)
         self._window_key = ("window", self._aot_dims, self.decode_steps)
         self._admit_key = ("admit", self._aot_dims)
         self._cow_key = ("cow", self._aot_dims)
         self.scheduler_overhead_s = 0.0
+        # wall seconds the last serve spent AOT-compiling in _aot_warm
+        self.warmup_s = 0.0
         # exposed for the slot-recycling tests: per-slot cache after the
         # last continuous serve, and the admission audit trail
         self.slot_cache = None
@@ -537,9 +552,11 @@ class ServingEngine:
             # the pool, allocator and index survive (the continuous
             # setup reuses them and resets the per-serve counters).
             self.prefix_cache = None
-        # serve-time fallbacks (AOT warmup failure, late kernel
-        # fallbacks) land in this engine's own ledger
-        with obslog.scope(self.fallback_ledger):
+        # serve-time fallbacks (late kernel fallbacks) land in this
+        # engine's own ledger; arrays the serve creates (pool, tables,
+        # token operands) land on the params' device
+        with obslog.scope(self.fallback_ledger), \
+                jax.default_device(self.device):
             # the worker is constructed BEFORE the try: if it raises,
             # there is no half-built worker for the finally to trip
             # over, and any engine exception mid-window always reaches
@@ -619,9 +636,9 @@ class ServingEngine:
             "queue_wait_p90": qw_h.quantile(0.90),
             "queue_wait_p99": qw_h.quantile(0.99),
             # countable silent degradations (repro.obs.log): jnp-kernel
-            # fallback at factory build, AOT warmup failure — counted
-            # by THIS engine's scoped ledger, so R replicas in one
-            # process each report only their own events
+            # fallback at factory build — counted by THIS engine's
+            # scoped ledger, so R replicas in one process each report
+            # only their own events
             "fallback_events": self.fallback_ledger.count(),
             # wall-clock the obs emitters spent recording (0.0 with
             # obs=None) — the measured-overhead guard: recording happens
@@ -890,6 +907,21 @@ class ServingEngine:
     def _ragged_aot_key(self, shape_key: tuple) -> tuple:
         return ("ragged", self._aot_dims, shape_key)
 
+    def warmed_executables(self) -> Dict[str, object]:
+        """This engine's AOT-compiled executables by ``dispatch:<kind>``
+        name plus the key's suffix (decode steps, ragged shape key) —
+        the ones ``_aot_warm`` compiled for this engine's dims and
+        device, not those of other engines sharing the factory memo."""
+        out = {}
+        for attr in ("_paged_decode_steps", "_paged_prefill",
+                     "_ragged_prefill", "_copy_block", "_decode_steps_fn",
+                     "_slot_prefill"):
+            exe = getattr(self, attr, None)
+            for key, compiled in (exe.aot.items() if exe else ()):
+                if key[1] == self._aot_dims:
+                    out[f"{exe.name}{list(key[2:])}"] = compiled
+        return out
+
     def _aot_warm(self, cache, kvc=None) -> None:
         """AOT-compile the continuous serve loop's executables at
         ``serve()`` start (``jit.lower(avals).compile()`` per shape
@@ -905,64 +937,60 @@ class ServingEngine:
         ragged keys a chunked serve typically opens with.  Ragged keys
         outside the warmed set (workload-dependent ChunkBatch shapes)
         fall back to jit-on-first-call, counted by exec_cache_misses as
-        before.  Warmup failure degrades to jit-on-first-call."""
+        before.  Every executable is compiled for the engine's device;
+        a compile the backend refuses raises here."""
         if not self.aot_warmup:
             return
+        t_warm = time.perf_counter()
         C, S, n = self.num_slots, self.input_bucket, self.decode_steps
+        on_dev = jax.sharding.SingleDeviceSharding(self.device)
 
         def sds(tree):
             return jax.tree.map(
-                lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype), tree)
+                lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                               sharding=a.sharding), tree)
+
+        def arg(shape):
+            return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=on_dev)
 
         p_s, c_s = sds(self.params), sds(cache)
-        tok_s = jax.ShapeDtypeStruct((C, 1), jnp.int32)
-        i32 = jax.ShapeDtypeStruct((), jnp.int32)
-        batch_s = {"tokens": jax.ShapeDtypeStruct((1, S), jnp.int32)}
-        try:
-            if self.kv == "paged":
-                nb = kvc.max_blocks_per_seq
-                tables_s = jax.ShapeDtypeStruct((C, nb), jnp.int32)
-                row_s = jax.ShapeDtypeStruct((nb,), jnp.int32)
-                self._paged_decode_steps.warm(
-                    self._window_key, (p_s, c_s, tok_s, tables_s),
-                    {"num_steps": n})
+        tok_s, i32 = arg((C, 1)), arg(())
+        batch_s = {"tokens": arg((1, S))}
+        if self.kv == "paged":
+            nb = kvc.max_blocks_per_seq
+            self._paged_decode_steps.warm(
+                self._window_key, (p_s, c_s, tok_s, arg((C, nb))),
+                {"num_steps": n})
+            if self.prefill == "stall":
+                self._paged_prefill.warm(
+                    self._admit_key, (p_s, c_s, batch_s, i32, arg((nb,))))
+            ragged_lens: set = set()
+            if self.prefix_cache_enabled:
+                self._copy_block.warm(self._cow_key, (c_s, i32, i32))
                 if self.prefill == "stall":
-                    self._paged_prefill.warm(
-                        self._admit_key, (p_s, c_s, batch_s, i32, row_s))
-                ragged_lens: set = set()
-                if self.prefix_cache_enabled:
-                    self._copy_block.warm(self._cow_key, (c_s, i32, i32))
-                    if self.prefill == "stall":
-                        # every reachable uncached-suffix length: prefix
-                        # matches are block-quantized, plus the L=1
-                        # full-match recompute
-                        bs = self.kv_block_size
-                        ragged_lens |= {S - k * bs
-                                        for k in range(1, S // bs + 1)
-                                        if S - k * bs > 0} | {1}
-                if self.prefill == "chunked":
-                    ragged_lens |= {min(self.chunk_size, S), S}
-                for L in sorted(ragged_lens):
-                    key = suffix_shape_key(L)
-                    TTp, Cp, Tp = key
-                    self._ragged_prefill.warm(
-                        self._ragged_aot_key(key),
-                        (p_s, c_s,
-                         {"tokens": jax.ShapeDtypeStruct((1, TTp),
-                                                         jnp.int32)},
-                         jax.ShapeDtypeStruct((TTp,), jnp.int32),
-                         jax.ShapeDtypeStruct((Cp, 4), jnp.int32),
-                         jax.ShapeDtypeStruct((Cp, nb), jnp.int32)),
-                        {"chunk_pad": Tp})
-            else:
-                self._decode_steps_fn.warm(
-                    self._window_key, (p_s, c_s, tok_s), {"num_steps": n})
-                self._slot_prefill.warm(
-                    self._admit_key, (p_s, c_s, batch_s, i32))
-        except Exception as exc:  # pragma: no cover - environment-specific
-            obslog.warn_once(logger, "aot-warmup",
-                             "AOT warmup failed (%s); executables will "
-                             "trace on first call", exc)
+                    # every reachable uncached-suffix length: prefix
+                    # matches are block-quantized, plus the L=1
+                    # full-match recompute
+                    bs = self.kv_block_size
+                    ragged_lens |= {S - k * bs
+                                    for k in range(1, S // bs + 1)
+                                    if S - k * bs > 0} | {1}
+            if self.prefill == "chunked":
+                ragged_lens |= {min(self.chunk_size, S), S}
+            for L in sorted(ragged_lens):
+                key = suffix_shape_key(L)
+                TTp, Cp, Tp = key
+                self._ragged_prefill.warm(
+                    self._ragged_aot_key(key),
+                    (p_s, c_s, {"tokens": arg((1, TTp))}, arg((TTp,)),
+                     arg((Cp, 4)), arg((Cp, nb))),
+                    {"chunk_pad": Tp})
+        else:
+            self._decode_steps_fn.warm(
+                self._window_key, (p_s, c_s, tok_s), {"num_steps": n})
+            self._slot_prefill.warm(
+                self._admit_key, (p_s, c_s, batch_s, i32))
+        self.warmup_s = time.perf_counter() - t_warm
 
     def _serve_continuous(self, requests: Sequence[Request], *,
                           step_offset: int = 0) -> Dict:

@@ -28,21 +28,28 @@ Placement protocol (the engine half of the parity discipline with
      replica's parity substream
      (``TraceRecorder.parity_events(replica=r)``).
 
-Device mapping is metadata, not magic: ``replica_devices()`` exposes
-``repro.launch.mesh.replica_groups`` — contiguous data-parallel device
-slices when the host has >= R devices, shared-device (thread-level)
-replicas otherwise (the CPU case: R engine instances time-share one
-host device, which is exactly what this in-process front-end models).
+Device mapping: ``replica_devices()`` is
+``repro.launch.mesh.replica_groups`` over ``devices`` (all local
+devices by default) — one device group per replica when there are at
+least R devices, round-robin shared devices otherwise (the CPU case: R
+engine instances time-share one host device).  Replica r's params are
+committed to the first device of its group (copied only when they live
+elsewhere), and each engine then allocates its KV pool and tables and
+compiles its executables on that device (``engine.params_device``).
+The replica groups are still served one after another.
 """
 
 from __future__ import annotations
 
 from typing import Dict, List, Optional, Sequence
 
+import jax
+
 from repro.kvcache import blocks_for_tokens
+from repro.launch.mesh import replica_groups
 from repro.obs import Observability
 
-from .engine import Request, ServingEngine
+from .engine import Request, ServingEngine, params_device
 from .router import ReplicaView, Router
 
 
@@ -51,7 +58,8 @@ class ReplicatedEngine:
 
     ``engine_kwargs`` forward verbatim to every replica's
     ``ServingEngine`` constructor (equal pools — ``kv_num_blocks`` is
-    PER replica, as in ``simulate_replicated``).
+    PER replica, as in ``simulate_replicated``).  ``devices`` are the
+    devices the replicas are spread over (default: all local devices).
     """
 
     def __init__(self, params, cfg, policy, profile, *,
@@ -59,6 +67,7 @@ class ReplicatedEngine:
                  router: Optional[Router] = None,
                  faults=None,
                  obs: Optional[Observability] = None,
+                 devices: Optional[Sequence] = None,
                  **engine_kwargs):
         if replicas < 1:
             raise ValueError(f"replicas must be >= 1, got {replicas}")
@@ -76,19 +85,20 @@ class ReplicatedEngine:
         self.faults = faults
         if faults is not None:
             faults.validate(self.R)
-        self.engines = [ServingEngine(params, cfg, policy, profile,
-                                      obs=obs,
-                                      faults=(None if faults is None
-                                              else faults.for_replica(r)),
-                                      **engine_kwargs)
-                        for r in range(self.R)]
+        self.groups = replica_groups(self.R, devices)
+        home = params_device(params)
+        self.engines = [ServingEngine(
+            params if group[0] == home else jax.device_put(params, group[0]),
+            cfg, policy, profile, obs=obs,
+            faults=None if faults is None else faults.for_replica(r),
+            **engine_kwargs)
+            for r, group in enumerate(self.groups)]
         self.placements: List[int] = []
 
     # ------------------------------------------------------------------
     def replica_devices(self) -> List[list]:
         """Device group per replica (``launch.mesh.replica_groups``)."""
-        from repro.launch.mesh import replica_groups
-        return replica_groups(self.R)
+        return self.groups
 
     def _need(self, req: Request) -> int:
         """The arrival's worst-case block reservation — the SAME

@@ -256,6 +256,28 @@ def test_ragged_chunked_prefill_sweep(lens, ctxs, dtype):
             np.asarray(want[c, :ln]).astype(np.float32), **_tol(dtype))
 
 
+@pytest.mark.parametrize("q_rows,kv_tile", [(32, 16), (64, 128), (256, 32)])
+def test_ragged_chunked_prefill_tiled(monkeypatch, q_rows, kv_tile):
+    """Query and in-chunk K/V tiles smaller than the chunk pad, as at the
+    engine's pads on the chip: every page is visited once per query tile
+    and in-chunk tiles above the diagonal are skipped, yet output and
+    pools still match the oracle."""
+    monkeypatch.setattr(rcp, "_Q_ROWS", q_rows)
+    monkeypatch.setattr(rcp, "_KV_TILE", kv_tile)
+    lens, ctxs = [16, 64, 128, 64, 16], [3, 0, 40, 16, 128]
+    q, kn, vn, kp, vp, tables, meta = _ragged_case(lens, ctxs, seed=5)
+    out, nk, nv = rcp.ragged_chunked_prefill(q, kn, vn, kp, vp, tables,
+                                             meta, interpret=True)
+    want, wk, wv = ref.ragged_chunked_prefill_ref(q, kn, vn, kp, vp,
+                                                  tables, meta)
+    np.testing.assert_array_equal(np.asarray(nk), np.asarray(wk))
+    np.testing.assert_array_equal(np.asarray(nv), np.asarray(wv))
+    for c, ln in enumerate(lens):
+        np.testing.assert_allclose(np.asarray(out[c, :ln]),
+                                   np.asarray(want[c, :ln]),
+                                   **_tol(jnp.float32))
+
+
 def test_ragged_matches_per_chunk_kernel():
     """Triangle closure: one fused launch over C chunks equals C
     separate ``chunked_prefill_attention`` launches run after a
