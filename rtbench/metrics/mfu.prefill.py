@@ -1,0 +1,8 @@
+"""Useful FLOPs of the prefill launches over their device time, percent
+of the chip's peak."""
+
+from rtbench import device
+
+
+def read(run):
+    return device.mfu(run, "prefill")
