@@ -129,5 +129,6 @@ def paged_flash_decode_attention(q, k_pages, v_pages, block_tables,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, KV, G, D), q.dtype),
         interpret=interpret,
+        name="paged_decode_kernel",    # the device op's name in a trace
     )(tables, lens, qt, kt, vt)
     return out.reshape(B, H, D)

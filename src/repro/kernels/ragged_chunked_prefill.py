@@ -299,6 +299,7 @@ def ragged_chunked_prefill(q, k_new, v_new, k_pages, v_pages, block_tables,
         # tables=1, qt=2, knt=3, vnt=4, knw=5,6, vnw=7,8, kt=9, vt=10
         input_output_aliases={9: 1, 10: 2},
         interpret=interpret,
+        name="ragged_prefill_kernel",  # the device op's name in a trace
     )(meta, tables, qt, knt, vnt, knw, knw, vnw, vnw, kt, vt)
     out = (out.reshape(C, KV, T, G, D).transpose(0, 2, 1, 3, 4)
            .reshape(C, T, H, D))

@@ -68,6 +68,7 @@ and the jitted prefill/decode executables are reused across batches.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import time
 from typing import Dict, List, Optional, Sequence
 
@@ -93,7 +94,7 @@ from repro.prefill import (ChunkScheduler, build_packed_arrays, pack_plans,
 
 from . import generate
 from .faults import shed_pass
-from .pipeline import CompletionWorker
+from .pipeline import CompletionWorker, host_phase
 
 EOS_ID = 1
 # max_len headroom past input_bucket + max_new_tokens.  It doubles as
@@ -346,6 +347,11 @@ class ServingEngine:
         self._admit_key = ("admit", self._aot_dims)
         self._cow_key = ("cow", self._aot_dims)
         self.scheduler_overhead_s = 0.0
+        # host wall seconds of the serve in flight by loop phase
+        # (pipeline.host_phase: predict, setup, admit, pack, launch,
+        # tables, wait, advance), reset per serve
+        self.host_phase_s: Dict[str, float] = {}
+        self._aot_misses0 = 0
         # wall seconds the last serve spent AOT-compiling in _aot_warm
         self.warmup_s = 0.0
         # exposed for the slot-recycling tests: per-slot cache after the
@@ -418,6 +424,13 @@ class ServingEngine:
                           d=d, input_len=float(len(req.text.split())),
                           true_out_len=0)
         return st
+
+    def _sim_tasks(self, requests: Sequence[Request]) -> List[prio.SimTask]:
+        """The requests in arrival order as SimTasks — the uncertainty
+        predictor and the priority point, host phase ``predict``."""
+        with host_phase(self.host_phase_s, "predict"):
+            return [self._to_sim_task(r)
+                    for r in sorted(requests, key=lambda r: r.arrival)]
 
     def _tokenize_padded(self, text: str) -> np.ndarray:
         return tokenize_padded(text, self.cfg.vocab_size,
@@ -541,6 +554,8 @@ class ServingEngine:
         self.decode_dispatches = 0
         self.decode_steps_total = 0
         self.decode_dispatch_trace = []
+        self.host_phase_s = {}
+        self._aot_misses0 = self._aot_misses()
         # the jnp-fallback warning is one-time PER SERVE (a process
         # running many engines must not mask later serves' fallbacks);
         # re-arm this engine's scoped ledger the same way
@@ -562,9 +577,7 @@ class ServingEngine:
             # over, and any engine exception mid-window always reaches
             # a close() that joins the daemon thread (close() is
             # idempotent, so double-teardown is safe too)
-            self._worker = CompletionWorker(
-                metrics=self.obs.metrics
-                if self.obs is not None else None)
+            self._worker = CompletionWorker()
             try:
                 if self.mode == "continuous":
                     if self.prefill == "chunked":
@@ -609,6 +622,11 @@ class ServingEngine:
             "max_response_s": float(rts.max()),
             "throughput_per_min": 60.0 * n / max(span, 1e-9),
             "scheduler_overhead_s": self.scheduler_overhead_s,
+            # this serve's host wall seconds by loop phase, and how many
+            # of its call_aot dispatches missed the AOT store and went
+            # through the jit function (compiling at an unseen shape)
+            "host_phase_s": dict(self.host_phase_s),
+            "aot_misses": self._aot_misses() - self._aot_misses0,
             "n_tasks": n,
             "tasks": done,
             "completion_order": [t.task.task_id for t in done],
@@ -729,8 +747,7 @@ class ServingEngine:
         return self.obs.health() if self.obs is not None else {}
 
     def _serve_batch(self, requests: Sequence[Request]) -> Dict:
-        pending = sorted(requests, key=lambda r: r.arrival)
-        sim_tasks = [self._to_sim_task(r) for r in pending]
+        sim_tasks = self._sim_tasks(requests)
         queue: List[prio.SimTask] = []
         bulk: List[prio.SimTask] = []
         done: List[prio.SimTask] = []
@@ -907,17 +924,25 @@ class ServingEngine:
     def _ragged_aot_key(self, shape_key: tuple) -> tuple:
         return ("ragged", self._aot_dims, shape_key)
 
+    def _executables(self) -> List[generate.JitExecutable]:
+        """The executables the continuous serve loops dispatch through
+        ``call_aot``."""
+        return [exe for exe in (getattr(self, attr, None) for attr in (
+            "_paged_decode_steps", "_paged_prefill", "_ragged_prefill",
+            "_copy_block", "_decode_steps_fn", "_slot_prefill"))
+            if exe is not None]
+
+    def _aot_misses(self) -> int:
+        return sum(exe.aot_misses for exe in self._executables())
+
     def warmed_executables(self) -> Dict[str, object]:
         """This engine's AOT-compiled executables by ``dispatch:<kind>``
         name plus the key's suffix (decode steps, ragged shape key) —
         the ones ``_aot_warm`` compiled for this engine's dims and
         device, not those of other engines sharing the factory memo."""
         out = {}
-        for attr in ("_paged_decode_steps", "_paged_prefill",
-                     "_ragged_prefill", "_copy_block", "_decode_steps_fn",
-                     "_slot_prefill"):
-            exe = getattr(self, attr, None)
-            for key, compiled in (exe.aot.items() if exe else ()):
+        for exe in self._executables():
+            for key, compiled in exe.aot.items():
                 if key[1] == self._aot_dims:
                     out[f"{exe.name}{list(key[2:])}"] = compiled
         return out
@@ -1000,20 +1025,22 @@ class ServingEngine:
         C = self.num_slots
         S = self.input_bucket
         paged = self.kv == "paged"
-        pending = sorted(requests, key=lambda r: r.arrival)
-        sim_tasks = [self._to_sim_task(r) for r in pending]
+        sim_tasks = self._sim_tasks(requests)
         n = len(sim_tasks)
         queue: List[prio.SimTask] = []
         bulk: List[prio.SimTask] = []
         done: List[prio.SimTask] = []
         pc = None
         kvc = alloc = None
-        if paged:
-            kvc, alloc, pc, cache = self._paged_setup()
-            reserved = [0] * C       # per-slot worst-case block holdback
-        else:
-            cache = transformer.init_slot_cache(self.cfg, C, self.max_len)
-        self._aot_warm(cache, kvc)
+        phase = functools.partial(host_phase, self.host_phase_s)
+        with phase("setup"):
+            if paged:
+                kvc, alloc, pc, cache = self._paged_setup()
+                reserved = [0] * C   # per-slot worst-case block holdback
+            else:
+                cache = transformer.init_slot_cache(self.cfg, C,
+                                                    self.max_len)
+            self._aot_warm(cache, kvc)
         slot_task: List[Optional[prio.SimTask]] = [None] * C
         slot_gen = [0] * C
         slot_cap = [0] * C
@@ -1259,59 +1286,67 @@ class ServingEngine:
                 nsteps = self.decode_steps
                 t0 = time.perf_counter()
                 if paged:
-                    self._extend_block_tables(active, slot_task,
-                                              slot_gen, slot_cap,
-                                              alloc, kvc, nsteps)
-                    window_tok, cache = self._paged_decode_steps.call_aot(
-                        self._window_key, self.params, cache,
-                        jnp.asarray(tokens), kvc.tables_device(),
-                        num_steps=nsteps)
+                    with phase("tables"):
+                        self._extend_block_tables(active, slot_task,
+                                                  slot_gen, slot_cap,
+                                                  alloc, kvc, nsteps)
+                        tables = kvc.tables_device()
+                    with phase("launch"):
+                        window_tok, cache = \
+                            self._paged_decode_steps.call_aot(
+                                self._window_key, self.params, cache,
+                                jnp.asarray(tokens), tables,
+                                num_steps=nsteps)
+                        self._worker.submit(window_tok, t0, kind="decode")
                 else:
-                    window_tok, cache = self._decode_steps_fn.call_aot(
-                        self._window_key, self.params, cache,
-                        jnp.asarray(tokens), num_steps=nsteps)
-                self._worker.submit(window_tok, t0)
-                window_host, dt = self._worker.collect()
-                if rf is not None:
-                    # straggler fault (SlowFault): stretch the window's
-                    # charge to the virtual clock.  Wall-only — parity
-                    # streams strip time fields by construction.
-                    dt *= rf.slow_factor(step)
-                now += dt
-                step += nsteps
-                self.decode_dispatches += 1
-                self.decode_steps_total += nsteps
-                self.decode_dispatch_trace.append(nsteps)
-                if paged:
-                    self.kv_util_samples.append(alloc.utilization())
-                else:
-                    self.kv_util_samples.append(len(active) / C)
-                if ob is not None:
-                    ob.inc("decode.dispatches")
-                    ob.inc("decode.steps", nsteps)
-                    ob.gauge("kv.util", self.kv_util_samples[-1])
-                    ob.counter_sample("kv.util", now,
-                                      self.kv_util_samples[-1])
-                    ob.span("decode.window", now - dt, dt, steps=nsteps,
-                            active=len(active))
-                    ob.event("decode_window", now, None, step,
-                             steps=nsteps, active=len(active), dur=dt)
-                self._advance_decode_window(
-                    active, window_host, now, dt, slot_task, slot_gen,
-                    slot_cap, tokens, done,
-                    alloc=alloc if paged else None,
-                    kvc=kvc if paged else None,
-                    reserved=reserved if paged else None, step=step)
-                if ob is not None:
-                    # snapshot cadence keys off ``step`` (the shared
-                    # iteration coordinate), AFTER window bookkeeping —
-                    # the simulator snapshots at the identical point
-                    ob.maybe_snapshot(
-                        now, step, queue_depth=len(queue),
-                        active=sum(t is not None for t in slot_task),
-                        kv_util=self.kv_util_samples[-1],
-                        wall={"collect_wait":
-                              self._worker.wait_snapshot()})
+                    with phase("launch"):
+                        window_tok, cache = self._decode_steps_fn.call_aot(
+                            self._window_key, self.params, cache,
+                            jnp.asarray(tokens), num_steps=nsteps)
+                        self._worker.submit(window_tok, t0, kind="decode")
+                with phase("wait"):
+                    window_host, dt = self._worker.collect()
+                with phase("advance"):
+                    if rf is not None:
+                        # straggler fault (SlowFault): stretch the
+                        # window's charge to the virtual clock.
+                        # Wall-only — parity streams strip time fields
+                        # by construction.
+                        dt *= rf.slow_factor(step)
+                    now += dt
+                    step += nsteps
+                    self.decode_dispatches += 1
+                    self.decode_steps_total += nsteps
+                    self.decode_dispatch_trace.append(nsteps)
+                    if paged:
+                        self.kv_util_samples.append(alloc.utilization())
+                    else:
+                        self.kv_util_samples.append(len(active) / C)
+                    if ob is not None:
+                        ob.inc("decode.dispatches")
+                        ob.inc("decode.steps", nsteps)
+                        ob.gauge("kv.util", self.kv_util_samples[-1])
+                        ob.counter_sample("kv.util", now,
+                                          self.kv_util_samples[-1])
+                        ob.span("decode.window", now - dt, dt,
+                                steps=nsteps, active=len(active))
+                        ob.event("decode_window", now, None, step,
+                                 steps=nsteps, active=len(active), dur=dt)
+                    self._advance_decode_window(
+                        active, window_host, now, dt, slot_task, slot_gen,
+                        slot_cap, tokens, done,
+                        alloc=alloc if paged else None,
+                        kvc=kvc if paged else None,
+                        reserved=reserved if paged else None, step=step)
+                    if ob is not None:
+                        # snapshot cadence keys off ``step`` (the shared
+                        # iteration coordinate), AFTER window bookkeeping
+                        # — the simulator snapshots at the identical point
+                        ob.maybe_snapshot(
+                            now, step, queue_depth=len(queue),
+                            active=sum(t is not None for t in slot_task),
+                            kv_util=self.kv_util_samples[-1],
+                            wall=self.host_phase_s)
                 continue
 
             if bulk and not queue:
@@ -1363,15 +1398,16 @@ class ServingEngine:
         C = self.num_slots
         S = self.input_bucket
         ob = self.obs
-        pending = sorted(requests, key=lambda r: r.arrival)
-        sim_tasks = [self._to_sim_task(r) for r in pending]
+        sim_tasks = self._sim_tasks(requests)
         n = len(sim_tasks)
         queue: List[prio.SimTask] = []
         bulk: List[prio.SimTask] = []
         done: List[prio.SimTask] = []
-        kvc, alloc, pc, cache = self._paged_setup()
+        phase = functools.partial(host_phase, self.host_phase_s)
+        with phase("setup"):
+            kvc, alloc, pc, cache = self._paged_setup()
+            self._aot_warm(cache, kvc)
         reserved = [0] * C           # per-slot worst-case block holdback
-        self._aot_warm(cache, kvc)
         sched = ChunkScheduler(self.chunk_size, self.token_budget,
                                metrics=ob.metrics if ob is not None
                                else None)
@@ -1388,205 +1424,212 @@ class ServingEngine:
         i = 0
         step = 0
         while len(done) < n:
-            while i < n and sim_tasks[i].r <= now + 1e-9:
-                if ob is not None:
-                    cls = sim_tasks[i].task.traffic_class
-                    ob.event("enqueue", sim_tasks[i].r,
-                             sim_tasks[i].task.task_id, step,
-                             **({"cls": cls} if cls else {}))
-                queue.append(sim_tasks[i])
-                i += 1
+            with phase("admit"):
+                while i < n and sim_tasks[i].r <= now + 1e-9:
+                    if ob is not None:
+                        cls = sim_tasks[i].task.traffic_class
+                        ob.event("enqueue", sim_tasks[i].r,
+                                 sim_tasks[i].task.task_id, step,
+                                 **({"cls": cls} if cls else {}))
+                    queue.append(sim_tasks[i])
+                    i += 1
 
-            # --- admissions: allocate slot + blocks, enqueue chunk job
-            free = [s for s in range(C) if slot_task[s] is None
-                    and s not in job_cap]
-            while queue and free:
-                running = ([t for t in slot_task if t is not None]
-                           + [j.task for j in sorted(sched.jobs,
-                                                     key=lambda j: j.seq)])
-                prev_queue = list(queue)
-                t0 = time.perf_counter()
-                task, lane, rest = self.policy.admit(list(queue), now,
-                                                     running)
-                self.scheduler_overhead_s += time.perf_counter() - t0
-                if task is None:
-                    break
-                queue = list(rest)
-                if lane == "cpu":
+                # --- admissions: allocate slot + blocks, enqueue chunk job
+                free = [s for s in range(C) if slot_task[s] is None
+                        and s not in job_cap]
+                while queue and free:
+                    running = ([t for t in slot_task if t is not None]
+                               + [j.task for j in sorted(sched.jobs,
+                                                         key=lambda j: j.seq)])
+                    prev_queue = list(queue)
+                    t0 = time.perf_counter()
+                    task, lane, rest = self.policy.admit(list(queue), now,
+                                                         running)
+                    self.scheduler_overhead_s += time.perf_counter() - t0
+                    if task is None:
+                        break
+                    queue = list(rest)
+                    if lane == "cpu":
+                        if ob is not None:
+                            ob.event("offload", now, task.task.task_id, step)
+                            ob.inc("sched.offloads")
+                        bulk.append(task)
+                        continue
+                    cap = self._cap(task.task)
+                    # identical reservation gate to the stall path — the
+                    # chunked simulator mirrors it bit for bit
+                    need = blocks_for_tokens(S + cap - 1, self.kv_block_size)
+                    if need > self.kv_num_blocks - sum(reserved):
+                        queue = prev_queue           # leave it queued
+                        self._rejected_ids.add(task.task.task_id)
+                        if ob is not None:
+                            ob.event("reject", now, task.task.task_id, step,
+                                     kv_blocks=need)
+                            ob.inc("sched.rejections")
+                        break
+                    slot = free.pop(0)
+                    reserved[slot] = need
+                    task.task.queue_wait_s = now - task.r
                     if ob is not None:
-                        ob.event("offload", now, task.task.task_id, step)
-                        ob.inc("sched.offloads")
-                    bulk.append(task)
-                    continue
-                cap = self._cap(task.task)
-                # identical reservation gate to the stall path — the
-                # chunked simulator mirrors it bit for bit
-                need = blocks_for_tokens(S + cap - 1, self.kv_block_size)
-                if need > self.kv_num_blocks - sum(reserved):
-                    queue = prev_queue           # leave it queued
-                    self._rejected_ids.add(task.task.task_id)
-                    if ob is not None:
-                        ob.event("reject", now, task.task.task_id, step,
-                                 kv_blocks=need)
-                        ob.inc("sched.rejections")
-                    break
-                slot = free.pop(0)
-                reserved[slot] = need
-                task.task.queue_wait_s = now - task.r
-                if ob is not None:
-                    ob.event("admit", now, task.task.task_id, step,
-                             slot=slot, u=task.u, kv_blocks=need)
-                    ob.inc("sched.admissions")
-                    ob.observe("queue_wait_s", task.task.queue_wait_s)
-                    ob.slo_observe("queue_wait",
-                                   task.task.traffic_class, now,
-                                   task.task.queue_wait_s)
-                # all of the prompt's blocks up front: every chunk
-                # position is backed, but kvc's DECODE table row stays
-                # on the trash page until prefill completes (the decode
-                # step writes a KV entry for every row, and a
-                # mid-prefill slot must not scribble real blocks)
-                toks = self._tokenize_padded(task.task.text)
-                start = 0
-                if pc is not None:
-                    # matched prefix blocks are shared into the table;
-                    # the chunk job covers only the uncached suffix
-                    plan = pc.admit(task.task.task_id, toks)
-                    start = plan.start
-                    if ob is not None and plan.matched_blocks:
-                        ob.event("prefix_hit", now, task.task.task_id,
-                                 step, cached_tokens=plan.start,
-                                 matched_blocks=plan.matched_blocks,
-                                 cow=len(plan.cow))
-                    for src, dst in plan.cow:
-                        cache = self._copy_block.call_aot(
-                            self._cow_key, cache, jnp.int32(src),
-                            jnp.int32(dst))
-                else:
-                    alloc.allocate_n(task.task.task_id,
-                                     alloc.blocks_for(S))
-                row = np.full((kvc.max_blocks_per_seq,), kvc.trash_block,
-                              np.int32)
-                tbl = alloc.table(task.task.task_id)
-                row[:len(tbl)] = tbl
-                job_row[slot] = row
-                job_tokens[slot] = toks
-                job_start[slot] = start
-                job_cap[slot] = cap
-                sched.add(task, slot, S - start,
-                          self.policy.assign_priority(task))
-                self.admission_log.append(
-                    {"task_id": task.task.task_id, "slot": slot,
-                     "step": step, "now": now})
+                        ob.event("admit", now, task.task.task_id, step,
+                                 slot=slot, u=task.u, kv_blocks=need)
+                        ob.inc("sched.admissions")
+                        ob.observe("queue_wait_s", task.task.queue_wait_s)
+                        ob.slo_observe("queue_wait",
+                                       task.task.traffic_class, now,
+                                       task.task.queue_wait_s)
+                    # all of the prompt's blocks up front: every chunk
+                    # position is backed, but kvc's DECODE table row stays
+                    # on the trash page until prefill completes (the decode
+                    # step writes a KV entry for every row, and a
+                    # mid-prefill slot must not scribble real blocks)
+                    toks = self._tokenize_padded(task.task.text)
+                    start = 0
+                    if pc is not None:
+                        # matched prefix blocks are shared into the table;
+                        # the chunk job covers only the uncached suffix
+                        plan = pc.admit(task.task.task_id, toks)
+                        start = plan.start
+                        if ob is not None and plan.matched_blocks:
+                            ob.event("prefix_hit", now, task.task.task_id,
+                                     step, cached_tokens=plan.start,
+                                     matched_blocks=plan.matched_blocks,
+                                     cow=len(plan.cow))
+                        for src, dst in plan.cow:
+                            cache = self._copy_block.call_aot(
+                                self._cow_key, cache, jnp.int32(src),
+                                jnp.int32(dst))
+                    else:
+                        alloc.allocate_n(task.task.task_id,
+                                         alloc.blocks_for(S))
+                    row = np.full((kvc.max_blocks_per_seq,), kvc.trash_block,
+                                  np.int32)
+                    tbl = alloc.table(task.task.task_id)
+                    row[:len(tbl)] = tbl
+                    job_row[slot] = row
+                    job_tokens[slot] = toks
+                    job_start[slot] = start
+                    job_cap[slot] = cap
+                    sched.add(task, slot, S - start,
+                              self.policy.assign_priority(task))
+                    self.admission_log.append(
+                        {"task_id": task.task.task_id, "slot": slot,
+                         "step": step, "now": now})
 
             # --- chunk phase: pack the budget, decode tokens first;
             # the WHOLE plan executes as one fused ragged launch
-            iter_stall = 0.0
-            active0 = [s for s in range(C) if slot_task[s] is not None]
-            plans = sched.schedule(len(active0)) if sched.has_jobs else []
-            batch_plan = pack_plans(plans)
+            with phase("pack"):
+                iter_stall = 0.0
+                active0 = [s for s in range(C) if slot_task[s] is not None]
+                plans = sched.schedule(len(active0)) if sched.has_jobs else []
+                batch_plan = pack_plans(plans)
+                if batch_plan is not None:
+                    key = batch_plan.shape_key
+                    hit = key in self._exec_keys
+                    if hit:
+                        self.exec_cache_hits += 1
+                    else:
+                        self._exec_keys.add(key)
+                        self.exec_cache_misses += 1
+                    if ob is not None:
+                        ob.event("exec_cache", now, None, step, hit=hit,
+                                 shape_key=str(key))
+                        ob.inc("exec_cache.hits" if hit
+                               else "exec_cache.misses")
+                    Tp = batch_plan.padded_chunk_len
+                    # chunk offsets are relative to the job (the uncached
+                    # suffix); job_start shifts them to absolute prompt
+                    # positions when a cached prefix was skipped.  The
+                    # packed layout itself (metadata rows, padding rules)
+                    # is encoded once in prefill.build_packed_arrays.
+                    entries = []
+                    for ch in batch_plan.chunks:
+                        s = ch.slot
+                        base = job_start[s] + ch.start
+                        entries.append((s, base,
+                                        job_tokens[s][base:base + ch.length],
+                                        job_row[s]))
+                    tokens_arr, token_chunk, meta, tabs = build_packed_arrays(
+                        key, entries, pad_slot=C,
+                        table_width=kvc.max_blocks_per_seq,
+                        trash_block=kvc.trash_block)
             if batch_plan is not None:
-                key = batch_plan.shape_key
-                hit = key in self._exec_keys
-                if hit:
-                    self.exec_cache_hits += 1
-                else:
-                    self._exec_keys.add(key)
-                    self.exec_cache_misses += 1
-                if ob is not None:
-                    ob.event("exec_cache", now, None, step, hit=hit,
-                             shape_key=str(key))
-                    ob.inc("exec_cache.hits" if hit
-                           else "exec_cache.misses")
-                Tp = batch_plan.padded_chunk_len
-                # chunk offsets are relative to the job (the uncached
-                # suffix); job_start shifts them to absolute prompt
-                # positions when a cached prefix was skipped.  The
-                # packed layout itself (metadata rows, padding rules)
-                # is encoded once in prefill.build_packed_arrays.
-                entries = []
-                for ch in batch_plan.chunks:
-                    s = ch.slot
-                    base = job_start[s] + ch.start
-                    entries.append((s, base,
-                                    job_tokens[s][base:base + ch.length],
-                                    job_row[s]))
-                tokens_arr, token_chunk, meta, tabs = build_packed_arrays(
-                    key, entries, pad_slot=C,
-                    table_width=kvc.max_blocks_per_seq,
-                    trash_block=kvc.trash_block)
                 stalled = any(t is not None for t in slot_task)
                 t0 = time.perf_counter()
-                cache, last_logits = self._ragged_prefill.call_aot(
-                    self._ragged_aot_key(key), self.params, cache,
-                    {"tokens": jnp.asarray(tokens_arr)},
-                    jnp.asarray(token_chunk), jnp.asarray(meta),
-                    jnp.asarray(tabs), chunk_pad=Tp)
-                # greedy-pick on device: only (Cp,) token ids cross the
-                # host link, not the (Cp, V) logits; the completion
-                # worker does the blocking readback off this thread
-                self._worker.submit(jnp.argmax(last_logits, axis=-1), t0)
-                next_ids, dt = self._worker.collect()
-                now += dt
-                self.prefill_dispatches += 1     # ONE launch, all chunks
-                if stalled:      # live slots waited out this launch
-                    self.prefill_stall_s += dt
-                    iter_stall += dt
-                if ob is not None:
-                    ob.inc("prefill.dispatches")
-                    ob.span("prefill.ragged", now - dt, dt,
-                            chunks=len(batch_plan.chunks),
-                            tokens=batch_plan.total_tokens)
-                    for ch in batch_plan.chunks:
-                        ob.event("prefill_chunk", now,
-                                 ch.job.task.task.task_id, step,
-                                 slot=ch.slot, start=ch.start,
-                                 length=ch.length, finishes=ch.finishes,
-                                 shape_key=str(key))
-                for ci, ch in enumerate(batch_plan.chunks):
-                    if not ch.finishes:
-                        continue
-                    s = ch.slot
-                    task = ch.job.task
-                    first = int(next_ids[ci])
-                    if pc is not None:
-                        pc.commit(task.task.task_id, job_tokens[s])
-                    cap = job_cap.pop(s)
-                    del job_tokens[s], job_row[s], job_start[s]
-                    task.start, task.lane = now, "gpu"
-                    task.task.start, task.task.lane = now, "gpu"
-                    task.task.slot = s
-                    task.task.out_tokens = [first]
-                    task.task.token_times = [now]
+                with phase("launch"):
+                    cache, last_logits = self._ragged_prefill.call_aot(
+                        self._ragged_aot_key(key), self.params, cache,
+                        {"tokens": jnp.asarray(tokens_arr)},
+                        jnp.asarray(token_chunk), jnp.asarray(meta),
+                        jnp.asarray(tabs), chunk_pad=Tp)
+                    # greedy-pick on device: only (Cp,) token ids cross the
+                    # host link, not the (Cp, V) logits; the completion
+                    # worker does the blocking readback off this thread
+                    self._worker.submit(jnp.argmax(last_logits, axis=-1),
+                                        t0, kind="prefill")
+                with phase("wait"):
+                    next_ids, dt = self._worker.collect()
+                with phase("advance"):
+                    now += dt
+                    self.prefill_dispatches += 1     # ONE launch, all chunks
+                    if stalled:      # live slots waited out this launch
+                        self.prefill_stall_s += dt
+                        iter_stall += dt
                     if ob is not None:
-                        ob.event("first_token", now, task.task.task_id,
-                                 step, slot=s)
-                        ob.slo_observe("ttft", task.task.traffic_class,
-                                       now, now - task.r)
-                    if first == self.eos_id or cap <= 1:
-                        task.finish = now
-                        task.task.finish, task.task.out_len = now, 1
-                        done.append(task)
+                        ob.inc("prefill.dispatches")
+                        ob.span("prefill.ragged", now - dt, dt,
+                                chunks=len(batch_plan.chunks),
+                                tokens=batch_plan.total_tokens)
+                        for ch in batch_plan.chunks:
+                            ob.event("prefill_chunk", now,
+                                     ch.job.task.task.task_id, step,
+                                     slot=ch.slot, start=ch.start,
+                                     length=ch.length, finishes=ch.finishes,
+                                     shape_key=str(key))
+                    for ci, ch in enumerate(batch_plan.chunks):
+                        if not ch.finishes:
+                            continue
+                        s = ch.slot
+                        task = ch.job.task
+                        first = int(next_ids[ci])
+                        if pc is not None:
+                            pc.commit(task.task.task_id, job_tokens[s])
+                        cap = job_cap.pop(s)
+                        del job_tokens[s], job_row[s], job_start[s]
+                        task.start, task.lane = now, "gpu"
+                        task.task.start, task.task.lane = now, "gpu"
+                        task.task.slot = s
+                        task.task.out_tokens = [first]
+                        task.task.token_times = [now]
                         if ob is not None:
-                            ob.event("complete", now, task.task.task_id,
-                                     step, lane="gpu", out_len=1)
-                            ob.event("evict", now, task.task.task_id,
+                            ob.event("first_token", now, task.task.task_id,
                                      step, slot=s)
-                            ob.inc("sched.completions")
-                            ob.complete_request(
-                                task.task.traffic_class, now,
-                                u=task.u, out_len=1,
-                                latency_s=now - task.r)
-                        alloc.free_sequence(task.task.task_id)
-                        reserved[s] = 0
-                    else:
-                        # install the real table: the slot joins THIS
-                        # iteration's decode step (as a stall admission
-                        # would), writing token 1's KV at position S
-                        kvc.set_table(s, alloc.table(task.task.task_id))
-                        slot_task[s] = task
-                        slot_gen[s], slot_cap[s] = 1, cap
-                        tokens[s, 0] = first
+                            ob.slo_observe("ttft", task.task.traffic_class,
+                                           now, now - task.r)
+                        if first == self.eos_id or cap <= 1:
+                            task.finish = now
+                            task.task.finish, task.task.out_len = now, 1
+                            done.append(task)
+                            if ob is not None:
+                                ob.event("complete", now, task.task.task_id,
+                                         step, lane="gpu", out_len=1)
+                                ob.event("evict", now, task.task.task_id,
+                                         step, slot=s)
+                                ob.inc("sched.completions")
+                                ob.complete_request(
+                                    task.task.traffic_class, now,
+                                    u=task.u, out_len=1,
+                                    latency_s=now - task.r)
+                            alloc.free_sequence(task.task.task_id)
+                            reserved[s] = 0
+                        else:
+                            # install the real table: the slot joins THIS
+                            # iteration's decode step (as a stall admission
+                            # would), writing token 1's KV at position S
+                            kvc.set_table(s, alloc.table(task.task.task_id))
+                            slot_task[s] = task
+                            slot_gen[s], slot_cap[s] = 1, cap
+                            tokens[s, 0] = first
             prefill_toks = sum(p.length for p in plans)
             self.prefill_stall_max_s = max(self.prefill_stall_max_s,
                                            iter_stall)
@@ -1605,41 +1648,45 @@ class ServingEngine:
                 # --- one N-step decode WINDOW over ALL slots (see
                 # _serve_continuous; identical launch/readback recipe)
                 t0 = time.perf_counter()
-                self._extend_block_tables(active, slot_task, slot_gen,
-                                          slot_cap, alloc, kvc, nsteps)
-                window_tok, cache = self._paged_decode_steps.call_aot(
-                    self._window_key, self.params, cache,
-                    jnp.asarray(tokens), kvc.tables_device(),
-                    num_steps=nsteps)
-                self._worker.submit(window_tok, t0)
-                window_host, dt = self._worker.collect()
-                now += dt
-                step += nsteps
-                self.decode_dispatches += 1
-                self.decode_steps_total += nsteps
-                self.kv_util_samples.append(alloc.utilization())
-                if ob is not None:
-                    ob.inc("decode.dispatches")
-                    ob.inc("decode.steps", nsteps)
-                    ob.gauge("kv.util", self.kv_util_samples[-1])
-                    ob.counter_sample("kv.util", now,
-                                      self.kv_util_samples[-1])
-                    ob.span("decode.window", now - dt, dt, steps=nsteps,
-                            active=len(active))
-                    ob.event("decode_window", now, None, step,
-                             steps=nsteps, active=len(active), dur=dt)
-                self._advance_decode_window(
-                    active, window_host, now, dt, slot_task, slot_gen,
-                    slot_cap, tokens, done, alloc=alloc, kvc=kvc,
-                    reserved=reserved, step=step)
-                if ob is not None:
-                    # same post-window snapshot point as the stall loop
-                    ob.maybe_snapshot(
-                        now, step, queue_depth=len(queue),
-                        active=sum(t is not None for t in slot_task),
-                        kv_util=self.kv_util_samples[-1],
-                        wall={"collect_wait":
-                              self._worker.wait_snapshot()})
+                with phase("tables"):
+                    self._extend_block_tables(active, slot_task, slot_gen,
+                                              slot_cap, alloc, kvc, nsteps)
+                    tables = kvc.tables_device()
+                with phase("launch"):
+                    window_tok, cache = self._paged_decode_steps.call_aot(
+                        self._window_key, self.params, cache,
+                        jnp.asarray(tokens), tables, num_steps=nsteps)
+                    self._worker.submit(window_tok, t0, kind="decode")
+                with phase("wait"):
+                    window_host, dt = self._worker.collect()
+                with phase("advance"):
+                    now += dt
+                    step += nsteps
+                    self.decode_dispatches += 1
+                    self.decode_steps_total += nsteps
+                    self.kv_util_samples.append(alloc.utilization())
+                    if ob is not None:
+                        ob.inc("decode.dispatches")
+                        ob.inc("decode.steps", nsteps)
+                        ob.gauge("kv.util", self.kv_util_samples[-1])
+                        ob.counter_sample("kv.util", now,
+                                          self.kv_util_samples[-1])
+                        ob.span("decode.window", now - dt, dt,
+                                steps=nsteps, active=len(active))
+                        ob.event("decode_window", now, None, step,
+                                 steps=nsteps, active=len(active), dur=dt)
+                    self._advance_decode_window(
+                        active, window_host, now, dt, slot_task, slot_gen,
+                        slot_cap, tokens, done, alloc=alloc, kvc=kvc,
+                        reserved=reserved, step=step)
+                    if ob is not None:
+                        # same post-window snapshot point as the stall
+                        # loop
+                        ob.maybe_snapshot(
+                            now, step, queue_depth=len(queue),
+                            active=sum(t is not None for t in slot_task),
+                            kv_util=self.kv_util_samples[-1],
+                            wall=self.host_phase_s)
                 continue
             if plans:
                 continue

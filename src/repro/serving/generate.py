@@ -81,13 +81,18 @@ class JitExecutable:
     scope (``dispatch:<name>`` — the factory kind, e.g.
     ``dispatch:ragged``), so a ``jax.profiler.trace()`` capture of a
     serve shows which executable each device launch belongs to; the
-    annotation is a no-op when no profiler is attached.
+    annotation is a no-op when no profiler is attached.  A ``call_aot``
+    that misses the AOT store runs under ``dispatch:<name>:jit`` instead
+    (a trace then shows which launch could have compiled) and counts in
+    ``aot_misses``, over the executable's life.
     """
 
     def __init__(self, fn, name: str = "jit"):
         self.fn = fn
         self.name = f"dispatch:{name}"
+        self.jit_name = f"{self.name}:jit"
         self.aot: dict = {}
+        self.aot_misses = 0
 
     def __call__(self, *args, **kwargs):
         with jax.profiler.TraceAnnotation(self.name):
@@ -105,10 +110,12 @@ class JitExecutable:
         """Dispatch through the warmed executable for ``key`` when one
         exists (array args only — statics were baked at lower time),
         else through the jit function."""
-        with jax.profiler.TraceAnnotation(self.name):
-            compiled = self.aot.get(key)
-            if compiled is not None:
+        compiled = self.aot.get(key)
+        if compiled is not None:
+            with jax.profiler.TraceAnnotation(self.name):
                 return compiled(*args)
+        self.aot_misses += 1
+        with jax.profiler.TraceAnnotation(self.jit_name):
             return self.fn(*args, **static_kwargs)
 
 
