@@ -55,7 +55,7 @@ from typing import Dict, List, Optional, Sequence
 import numpy as np
 
 from repro.kvcache import (BlockAllocator, PrefixCache, blocks_for_tokens,
-                           window_target_tokens)
+                           decode_pages_walked, window_target_tokens)
 from repro.obs.metrics import Histogram
 from repro.prefill import ChunkScheduler, pack_plans, suffix_shape_key
 
@@ -1000,6 +1000,12 @@ class _ReplicaSim:
                 # as here; event timestamps are model time)
                 obs.inc("decode.dispatches")
                 obs.inc("decode.steps", nsteps)
+                if kv_model:
+                    # the paged decode kernel's pages (live slots only)
+                    obs.inc("decode.pages", sum(
+                        decode_pages_walked(prompt_len, produced[s], nsteps,
+                                            self.kv_block_size)
+                        for s in active))
                 obs.gauge("kv.util", kv_util[-1])
                 obs.counter_sample("kv.util", self.now, kv_util[-1])
                 obs.span("decode.window", self.now,

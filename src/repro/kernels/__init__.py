@@ -11,8 +11,9 @@ oracle (ref.py):
   decode_attention         — flash-decode GQA attention over long KV
                              caches
   paged_decode_attention   — flash-decode over a block table (paged KV
-                             cache; indirect page gather via
-                             scalar-prefetch BlockSpec index_map)
+                             cache; a row's live pages DMA'd by hand
+                             from the pool's own layout, 128 tokens
+                             a chunk, double-buffered)
   chunked_prefill_attention — one prompt chunk over a paged prefix
                              (block-table scalar prefetch, (T*G, D)
                              query tile)

@@ -7,19 +7,21 @@ through a ``(B, num_blocks)`` block table — KV memory scales with live
 tokens, not ``B * max_len`` (vLLM's PagedAttention, here as a Pallas
 TPU kernel).
 
-The indirection happens in the BlockSpec index_map, not the kernel
-body: the block table rides in as a scalar-prefetch operand
-(``PrefetchScalarGridSpec``), so when the sequential innermost grid
-dimension walks a sequence's logical blocks, Mosaic's pipeline DMAs the
-*physical* page ``tables[b, i]`` into VMEM — an indirect gather at full
-copy bandwidth, with no (B, max_len) contiguous view ever materialized
-(the pure-jnp fallback in ``kernels/ref.py`` materializes exactly that
-view; it is the semantic oracle, not the production path).
+The pools stay in HBM in their own ``(N, bs, KV, D)`` layout
+(``memory_space=pl.ANY``) and the kernel fetches pages by hand: the
+block table and the lengths ride in as scalar-prefetch operands, so
+the page ids are known before any DMA is issued.
 
-  grid = (B, KV, nb) — innermost sequential over table entries;
-  per step: q-group tile (G, D) x page (block_size, D) on the MXU,
-  masked by ``logical_pos < seq_len`` (table padding resolves to page 0,
-  fully masked); running (m, l, acc) scratch identical to decode_attention.
+  grid = (B,) — one step a row, sequential ("arbitrary": a v5e has one
+  TensorCore, so a parallel axis buys nothing);
+  per row: the live pages ``min(cdiv(seq_len, bs), nb)`` only, in chunks
+  of ``P = min(128 // bs, nb)`` pages so a score tile spans the 128
+  lanes.  Each chunk's whole K and V pages (both heads' halves together)
+  are DMA'd into one slot of a two-slot VMEM buffer while the other
+  slot is computed; the next row's first chunk is started at the end of
+  the row.  A ``seq_len == 0`` row fetches nothing and returns zeros.
+  Per chunk and KV head: q-group tile (G, D) x (P * bs, D) on the MXU,
+  masked by ``logical_pos < seq_len``; running (m, l, acc) in float32.
 """
 
 from __future__ import annotations
@@ -34,101 +36,152 @@ from jax.experimental.pallas import tpu as pltpu
 NEG_INF = -1e30
 
 
-def _paged_fd_kernel(tables_ref, lens_ref, q_ref, k_ref, v_ref, o_ref,
-                     m_scr, l_scr, acc_scr, *, scale: float,
-                     block_size: int):
+def _paged_fd_kernel(tables_ref, lens_ref, q_ref, k_hbm, v_hbm, o_ref,
+                     k_buf, v_buf, sem, chunk_ref, *, scale: float,
+                     block_size: int, pages_per_chunk: int, nb: int):
     b = pl.program_id(0)
-    ki = pl.program_id(2)
-    nk = pl.num_programs(2)
+    nrows = pl.num_programs(0)
+    bs, P = block_size, pages_per_chunk
+    KV = k_buf.shape[3]
+    _, H, D = q_ref.shape
+    G = H // KV
 
-    @pl.when(ki == 0)
-    def _init():
-        m_scr[...] = jnp.full_like(m_scr, NEG_INF)
-        l_scr[...] = jnp.zeros_like(l_scr)
-        acc_scr[...] = jnp.zeros_like(acc_scr)
+    def live_pages(row):
+        return jnp.minimum(pl.cdiv(lens_ref[row], bs), nb)
 
-    q = q_ref[0, 0].astype(jnp.float32)          # (G, D)
-    k = k_ref[0, 0].astype(jnp.float32)          # (bs, D) — page tables[b,ki]
-    v = v_ref[0, 0].astype(jnp.float32)
-    s = jax.lax.dot_general(
-        q, k, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32) * scale    # (G, bs)
-    # logical positions covered by this table entry; padding entries
-    # (ki >= ceil(seq_len / bs)) mask out entirely
-    pos = (ki * block_size
-           + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1))
-    valid = pos < lens_ref[b]
-    s = jnp.where(valid, s, NEG_INF)
+    def chunk_copies(row, c, slot):
+        """The DMAs of chunk ``c`` of ``row`` into buffer ``slot``: one
+        per live page and pool, each guarded by the same predicate at
+        start and at wait (a dead page is neither fetched nor waited
+        for; its buffer entry keeps earlier, finite contents, which the
+        mask zeroes)."""
+        n = live_pages(row)
+        out = []
+        for j in range(P):
+            page_idx = c * P + j
+            live = page_idx < n
+            # read whether or not the page is live: keep it in the row
+            page = tables_ref[row * nb + jnp.minimum(page_idx, nb - 1)]
+            out.append((live, pltpu.make_async_copy(
+                k_hbm.at[page], k_buf.at[slot, j], sem.at[0, slot])))
+            out.append((live, pltpu.make_async_copy(
+                v_hbm.at[page], v_buf.at[slot, j], sem.at[1, slot])))
+        return out
 
-    m_prev = m_scr[...]
-    m_new = jnp.maximum(m_prev, s.max(axis=-1))
-    # re-mask after the shift: when every position so far is masked,
-    # m_new == s == NEG_INF and exp(s - m_new) would be 1, averaging
-    # garbage page contents into the row (a seq_len == 0 row then
-    # returns zeros instead)
-    p = jnp.where(valid, jnp.exp(s - m_new[:, None]), 0.0)
-    corr = jnp.exp(m_prev - m_new)
-    l_scr[...] = l_scr[...] * corr + p.sum(axis=-1)
-    acc_scr[...] = acc_scr[...] * corr[:, None] + jax.lax.dot_general(
-        p, v, (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)
-    m_scr[...] = m_new
+    def start(row, c, slot):
+        for live, cp in chunk_copies(row, c, slot):
+            pl.when(live)(cp.start)
 
-    @pl.when(ki == nk - 1)
-    def _finalize():
-        o_ref[0, 0] = (acc_scr[...]
-                       / jnp.maximum(l_scr[...], 1e-30)[:, None]
-                       ).astype(o_ref.dtype)
+    def wait(row, c, slot):
+        for live, cp in chunk_copies(row, c, slot):
+            pl.when(live)(cp.wait)
+
+    @pl.when(b == 0)
+    def _first():
+        # a dead page's V entry meets probability 0, and 0 * NaN is not
+        # zero: start the buffer finite (a masked K entry never counts)
+        v_buf[...] = jnp.zeros_like(v_buf)
+        chunk_ref[0] = 0
+        start(0, 0, 0)
+
+    eff_len = jnp.minimum(lens_ref[b], nb * bs)
+    n_chunks = pl.cdiv(live_pages(b), P)
+    first_chunk = chunk_ref[0]        # chunks consumed by earlier rows
+    q = q_ref[0].astype(jnp.float32)  # (H, D): KV head h owns rows h*G..
+
+    def body(c, carry):
+        slot = (first_chunk + c) % 2
+
+        @pl.when(c + 1 < n_chunks)
+        def _next():
+            start(b, c + 1, 1 - slot)
+
+        wait(b, c, slot)
+        pos = (c * (P * bs)
+               + jax.lax.broadcasted_iota(jnp.int32, (G, P * bs), 1))
+        valid = pos < eff_len
+        out = []
+        for h in range(KV):
+            m_prev, l_prev, acc_prev = carry[h]
+            k = k_buf[slot, :, :, h, :].reshape(P * bs, D).astype(jnp.float32)
+            v = v_buf[slot, :, :, h, :].reshape(P * bs, D).astype(jnp.float32)
+            s = jax.lax.dot_general(
+                q[h * G:(h + 1) * G], k, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32) * scale   # (G, P*bs)
+            s = jnp.where(valid, s, NEG_INF)
+            m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
+            # re-mask after the shift: when every position so far is
+            # masked, m_new == s == NEG_INF and exp(s - m_new) would be 1
+            p = jnp.where(valid, jnp.exp(s - m_new), 0.0)
+            corr = jnp.exp(m_prev - m_new)
+            out.append((m_new,
+                        l_prev * corr + p.sum(axis=-1, keepdims=True),
+                        acc_prev * corr + jax.lax.dot_general(
+                            p, v, (((1,), (0,)), ((), ())),
+                            preferred_element_type=jnp.float32)))
+        return tuple(out)
+
+    init = tuple((jnp.full((G, 1), NEG_INF, jnp.float32),
+                  jnp.zeros((G, 1), jnp.float32),
+                  jnp.zeros((G, D), jnp.float32)) for _ in range(KV))
+    res = jax.lax.fori_loop(0, n_chunks, body, init)
+    for h in range(KV):
+        _, l_fin, acc = res[h]
+        o_ref[0, h * G:(h + 1) * G] = (
+            acc / jnp.maximum(l_fin, 1e-30)).astype(o_ref.dtype)
+
+    next_chunk = first_chunk + n_chunks
+    chunk_ref[0] = next_chunk
+
+    @pl.when(b + 1 < nrows)
+    def _next_row():
+        start(b + 1, 0, next_chunk % 2)
 
 
 def paged_flash_decode_attention(q, k_pages, v_pages, block_tables,
                                  seq_lens, *, interpret: bool = False):
     """q: (B, H, D); pages: (N, bs, KV, D); block_tables: (B, nb) i32
     physical page ids (pad with any valid id, e.g. 0); seq_lens: (B,)
-    i32 valid logical lengths.  Returns (B, H, D).
+    i32 valid logical lengths, clamped to ``nb * bs``.  Returns
+    (B, H, D).
 
-    A ``seq_len == 0`` row attends to nothing and returns zeros (the
-    pure-jnp oracle softmaxes over all -inf and yields NaN there, so
-    only rows with ``seq_len >= 1`` are comparable against it).
+    Only a row's first ``min(cdiv(seq_len, bs), nb)`` table entries are
+    read.  A ``seq_len == 0`` row attends to nothing and returns zeros
+    (the pure-jnp oracle softmaxes over all -inf and yields NaN there,
+    so only rows with ``seq_len >= 1`` are comparable against it).
     """
     B, H, D = q.shape
     N, bs, KV, _ = k_pages.shape
     _, nb = block_tables.shape
     G = H // KV
-    scale = 1.0 / (D ** 0.5)
+    P = max(1, min(128 // bs, nb))
 
-    qt = q.reshape(B, KV, G, D)
-    kt = k_pages.transpose(2, 0, 1, 3)           # (KV, N, bs, D)
-    vt = v_pages.transpose(2, 0, 1, 3)
-    tables = block_tables.astype(jnp.int32)
-    lens = seq_lens.astype(jnp.int32)
-
-    kernel = functools.partial(_paged_fd_kernel, scale=scale,
-                               block_size=bs)
+    kernel = functools.partial(_paged_fd_kernel, scale=1.0 / (D ** 0.5),
+                               block_size=bs, pages_per_chunk=P, nb=nb)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,                   # block_tables, seq_lens
-        grid=(B, KV, nb),
+        grid=(B,),
         in_specs=[
-            pl.BlockSpec((1, 1, G, D), lambda b, h, i, t, s: (b, h, 0, 0)),
-            # the indirection: page tables[b, i] streams into VMEM
-            pl.BlockSpec((1, 1, bs, D),
-                         lambda b, h, i, t, s: (h, t[b, i], 0, 0)),
-            pl.BlockSpec((1, 1, bs, D),
-                         lambda b, h, i, t, s: (h, t[b, i], 0, 0)),
+            pl.BlockSpec((1, H, D), lambda b, t, s: (b, 0, 0)),
+            pl.BlockSpec(memory_space=pl.ANY),   # pools stay in HBM
+            pl.BlockSpec(memory_space=pl.ANY),
         ],
-        out_specs=pl.BlockSpec((1, 1, G, D),
-                               lambda b, h, i, t, s: (b, h, 0, 0)),
+        out_specs=pl.BlockSpec((1, H, D), lambda b, t, s: (b, 0, 0)),
         scratch_shapes=[
-            pltpu.VMEM((G,), jnp.float32),
-            pltpu.VMEM((G,), jnp.float32),
-            pltpu.VMEM((G, D), jnp.float32),
+            pltpu.VMEM((2, P, bs, KV, D), k_pages.dtype),
+            pltpu.VMEM((2, P, bs, KV, D), v_pages.dtype),
+            pltpu.SemaphoreType.DMA((2, 2)),     # (K|V, slot)
+            pltpu.SMEM((1,), jnp.int32),         # chunks consumed so far
         ],
     )
     out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, KV, G, D), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((B, H, D), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
         interpret=interpret,
         name="paged_decode_kernel",    # the device op's name in a trace
-    )(tables, lens, qt, kt, vt)
-    return out.reshape(B, H, D)
+    )(block_tables.astype(jnp.int32).reshape(-1),
+      seq_lens.astype(jnp.int32), q, k_pages, v_pages)
+    return out

@@ -23,6 +23,9 @@ system prompts) can map many sequences to the same physical blocks.
       N-step launch, clamped at the admission reservation so overhang
       writes past EOS/cap never touch foreign blocks and rejection
       decisions are independent of N.
+  allocator.decode_pages_walked — the pages the paged decode kernel
+      reads for one slot over an N-step window (its live table
+      entries), shared by the engine's and the simulator's count.
   paged.PagedKVCache — device-side paged K/V store (one
       ``(num_blocks, block_size, kv_heads, head_dim)`` array pair per
       layer) plus the pure-jnp gather/scatter/copy primitives the
@@ -46,6 +49,6 @@ tables).  See docs/ARCHITECTURE.md for the full configuration matrix.
 """
 
 from .allocator import (BlockAllocator, blocks_for_tokens,  # noqa: F401
-                        window_target_tokens)
+                        decode_pages_walked, window_target_tokens)
 from .paged import PagedKVCache  # noqa: F401
 from .prefix import PrefixCache, block_hashes  # noqa: F401

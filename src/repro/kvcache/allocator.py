@@ -76,6 +76,20 @@ def window_target_tokens(prompt_len: int, produced: int, cap: int,
     return prompt_len + min(produced + steps, cap) - 1
 
 
+def decode_pages_walked(prompt_len: int, produced: int, steps: int,
+                        block_size: int) -> int:
+    """Pages the paged decode kernel reads for one live slot over an
+    N-step decode window: step j attends over ``prompt + produced + j``
+    positions (the write position of ``window_target_tokens`` plus
+    one), ``ceil(len / block_size)`` pages.  The engine's tables are
+    sized for the window's overhang, so this never passes their width.
+    Shared by the engine's ``decode_page_share`` and the simulator's
+    ``decode.pages`` counter, which must agree for parity.
+    """
+    return sum(blocks_for_tokens(prompt_len + produced + j, block_size)
+               for j in range(steps))
+
+
 class OutOfBlocksError(RuntimeError):
     """Raised when an allocation is requested from an empty free list.
 

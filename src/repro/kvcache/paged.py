@@ -192,6 +192,9 @@ class PagedKVCache:
         # decode step writes a KV entry for EVERY row, and evicted
         # (dead) rows must not scribble over blocks that may already
         # belong to a newly admitted sequence — their tables point here.
+        # It is the pool's LAST page: the paged decode call site
+        # (transformer._attn_decode_paged) reads a table that starts
+        # here as a dead row and gives the kernel a length of 0.
         self.trash_block = num_blocks
         self.state = transformer.init_paged_cache(
             cfg, num_slots, num_blocks + 1, block_size, dtype)

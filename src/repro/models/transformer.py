@@ -210,8 +210,8 @@ def _attn_decode_paged(p, x, pages_k, pages_v, pos, tables, cfg,
     engine matches the contiguous engine token for token.
 
     ``use_pallas`` routes the attention through the Pallas
-    ``paged_decode_attention`` kernel, which streams pages through VMEM
-    via scalar-prefetch block-table indirection (the production TPU
+    ``paged_decode_attention`` kernel, which DMAs each row's live pages
+    from the pool into VMEM and skips dead rows (the production TPU
     path); the default jnp path gathers a transient contiguous view —
     exact, but O(slots * max_len) scratch per layer.  Off TPU the
     kernel body runs in interpret mode (correct, slow;
@@ -224,8 +224,13 @@ def _attn_decode_paged(p, x, pages_k, pages_v, pos, tables, cfg,
     new_k = paged_lib.scatter_token(pages_k, k[:, 0], tables, pos)
     new_v = paged_lib.scatter_token(pages_v, v[:, 0], tables, pos)
     if use_pallas:
+        # rows whose table starts at the trash page (the pool's last
+        # page, ``PagedKVCache.trash_block == num_blocks``) are evicted
+        # slots or slots still in prefill: the engine discards their
+        # outputs, so the kernel walks none of their pages
+        dead = tables[:, 0] == pages_k.shape[0] - 1
         attn = pfd_kernel.paged_flash_decode_attention(
-            q[:, 0], new_k, new_v, tables, pos + 1,
+            q[:, 0], new_k, new_v, tables, jnp.where(dead, 0, pos + 1),
             interpret=kernel_ops._default_interpret())[:, None]
     else:
         k_seq = paged_lib.gather_tokens(new_k, tables)  # (B, nb*bs, KV, D)

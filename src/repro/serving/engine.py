@@ -82,7 +82,7 @@ from repro.core import scheduler as sched_lib
 from repro.core.simulator import _pct as pct  # noqa: F401 - re-exported
 from repro.core.personas import Persona
 from repro.kvcache import (BlockAllocator, blocks_for_tokens,
-                           window_target_tokens)
+                           decode_pages_walked, window_target_tokens)
 from repro.kvcache.paged import PagedKVCache
 from repro.kvcache.prefix import PrefixCache
 from repro.models import transformer
@@ -397,6 +397,7 @@ class ServingEngine:
         # mirrors all three.
         self.decode_dispatches = 0
         self.decode_steps_total = 0
+        self.decode_page_share_sum = 0.0
         self.decode_dispatch_trace: List[int] = []
         # completion worker (serving.pipeline) of the serve in flight
         self._worker: Optional[CompletionWorker] = None
@@ -553,6 +554,7 @@ class ServingEngine:
         self._exec_keys = set()
         self.decode_dispatches = 0
         self.decode_steps_total = 0
+        self.decode_page_share_sum = 0.0
         self.decode_dispatch_trace = []
         self.host_phase_s = {}
         self._aot_misses0 = self._aot_misses()
@@ -691,6 +693,11 @@ class ServingEngine:
             "decode_dispatches": self.decode_dispatches,
             "decode_steps_executed": self.decode_steps_total,
             "decode_dispatch_trace": list(self.decode_dispatch_trace),
+            # paged decode: pages the kernel walked over the pages it
+            # could walk (every slot's whole table), meaned over decode
+            # launches; dead rows walk none (0.0 without a paged decode)
+            "decode_page_share": (self.decode_page_share_sum
+                                  / max(self.decode_dispatches, 1)),
             # prefix-cache metrics (kvcache.prefix counters; the
             # simulator's cache model reports the identical fields —
             # the engine-vs-sim parity tests compare them directly).
@@ -810,7 +817,8 @@ class ServingEngine:
         Overhang writes past the clamp land on the trash page via the
         scatter primitives' table-width clamp.  Shared by the stall and
         chunked serve loops; ``steps=1`` is the original synchronous
-        per-step rule."""
+        per-step rule.  Also counts the pages the decode kernel walks
+        in the window (``decode_page_share``, ``decode.pages``)."""
         S = self.input_bucket
         for s in active:
             tid = slot_task[s].task.task_id
@@ -820,6 +828,15 @@ class ServingEngine:
             while target > have:
                 kvc.extend_table(s, have, alloc.allocate(tid))
                 have += 1
+        # the pages the decode kernel will walk: the live slots' live
+        # table entries (every other row's table is the trash page's)
+        nb = kvc.max_blocks_per_seq
+        walked = sum(decode_pages_walked(S, slot_gen[s], steps,
+                                         self.kv_block_size)
+                     for s in active)
+        self.decode_page_share_sum += walked / (self.num_slots * nb * steps)
+        if self.obs is not None:
+            self.obs.inc("decode.pages", walked)
 
     def _advance_decode_window(self, active, window_host, now, dt,
                                slot_task, slot_gen, slot_cap, tokens,

@@ -61,14 +61,51 @@ def test_flash_decode_sweep(B, S, H, KV, D, block_k, dtype):
                                want.astype(jnp.float32), **_tol(dtype))
 
 
+def _paged_case(B, N, nb, block_size, layout, rng):
+    """Block tables and lengths for one sweep case.  ``random``:
+    permuted tables, lengths in [1, nb * block_size].  The kernel walks
+    a row's live pages in chunks of ``P = min(128 // block_size, nb)``,
+    so the others aim at its edges: lengths on page and chunk
+    boundaries, lengths past the table (clamped to ``nb * block_size``),
+    two rows sharing their prefix pages, and a row whose table is one
+    page throughout with length 0 (a dead slot: returns zeros)."""
+    tables = np.stack([rng.permutation(N)[:nb]
+                       for _ in range(B)]).astype(np.int32)
+    lens = rng.integers(1, nb * block_size + 1, (B,))
+    P = min(128 // block_size, nb)
+    if layout == "boundary":
+        lens = np.array([block_size, P * block_size, nb * block_size])[:B]
+    elif layout == "clamped":
+        lens = nb * block_size + np.array([7, 100])[:B]
+    elif layout == "shared":
+        tables[1, :2] = tables[0, :2]
+        lens[:2] = [2 * block_size + 3, 2 * block_size]
+    elif layout == "empty":
+        tables[0] = N - 1
+        lens[0] = 0
+    return jnp.asarray(tables), jnp.asarray(lens.astype(np.int32))
+
+
 @pytest.mark.parametrize("dtype", DTYPES)
-@pytest.mark.parametrize("B,H,KV,D,block_size,nb", [
-    (2, 4, 2, 32, 16, 4),       # GQA, 4-entry tables
-    (1, 8, 1, 64, 32, 3),       # MQA
-    (3, 4, 4, 16, 64, 2),       # MHA, big pages
-    (2, 8, 2, 128, 16, 5),      # long table, wide heads
+@pytest.mark.parametrize("B,H,KV,D,block_size,nb,layout", [
+    pytest.param(2, 4, 2, 32, 16, 4, "random",
+                 id="2-4-2-32-16-4"),        # GQA, 4-entry tables
+    pytest.param(1, 8, 1, 64, 32, 3, "random",
+                 id="1-8-1-64-32-3"),        # MQA
+    pytest.param(3, 4, 4, 16, 64, 2, "random",
+                 id="3-4-4-16-64-2"),        # MHA, big pages
+    pytest.param(2, 8, 2, 128, 16, 5, "random",
+                 id="2-8-2-128-16-5"),       # long table, wide heads
+    # StarCoder2-3B's heads and the cell's 24-entry tables
+    pytest.param(3, 24, 2, 128, 16, 24, "random", id="sc2-heads"),
+    pytest.param(2, 4, 2, 32, 16, 11, "random",
+                 id="nb-not-multiple-of-P"),  # chunks of 8 and 3
+    pytest.param(3, 8, 2, 64, 16, 10, "boundary", id="page-boundaries"),
+    pytest.param(2, 8, 2, 64, 16, 11, "clamped", id="len-past-table"),
+    pytest.param(2, 8, 2, 64, 16, 6, "shared", id="shared-prefix-page"),
+    pytest.param(2, 8, 2, 64, 16, 6, "empty", id="dead-row"),
 ])
-def test_paged_decode_sweep(B, H, KV, D, block_size, nb, dtype):
+def test_paged_decode_sweep(B, H, KV, D, block_size, nb, layout, dtype):
     """Paged flash-decode vs the block-table gather oracle across block
     sizes and RAGGED per-sequence lengths (tables deliberately permuted
     so physical order != logical order)."""
@@ -80,16 +117,19 @@ def test_paged_decode_sweep(B, H, KV, D, block_size, nb, dtype):
     vp = jax.random.normal(ks[2], (N, block_size, KV, D),
                            jnp.float32).astype(dtype)
     rng = np.random.default_rng(B * 131 + block_size)
-    tables = jnp.asarray(np.stack(
-        [rng.permutation(N)[:nb] for _ in range(B)]).astype(np.int32))
-    lens = jnp.asarray(
-        rng.integers(1, nb * block_size + 1, (B,)).astype(np.int32))
+    tables, lens = _paged_case(B, N, nb, block_size, layout, rng)
     out = pfd.paged_flash_decode_attention(q, kp, vp, tables, lens,
                                            interpret=True)
-    want = ref.paged_decode_attention_ref(q, kp, vp, tables, lens)
     assert out.shape == (B, H, D) and out.dtype == dtype
-    np.testing.assert_allclose(out.astype(jnp.float32),
-                               want.astype(jnp.float32), **_tol(dtype))
+    live = np.asarray(lens) > 0
+    # the oracle is NaN on a length-0 row; the kernel returns zeros
+    want = ref.paged_decode_attention_ref(q, kp, vp, tables,
+                                          jnp.maximum(lens, 1))
+    np.testing.assert_array_equal(
+        np.asarray(out.astype(jnp.float32))[~live], 0.0)
+    np.testing.assert_allclose(np.asarray(out.astype(jnp.float32))[live],
+                               np.asarray(want.astype(jnp.float32))[live],
+                               **_tol(dtype))
 
 
 def test_paged_decode_matches_contiguous_decode():

@@ -19,6 +19,7 @@ Acceptance properties of the kvcache subsystem (ISSUE 2):
 import dataclasses
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
@@ -27,6 +28,7 @@ from repro.core import datagen, personas, priority as prio
 from repro.core import scheduler as sched, simulator
 from repro.kvcache import paged as paged_lib
 from repro.models import model as model_lib, transformer
+from repro.obs import Observability
 from repro.serving.engine import Request, ServingEngine
 
 SLOTS = 3
@@ -190,3 +192,61 @@ def test_paged_validation():
     with pytest.raises(NotImplementedError):
         ServingEngine(None, hyb_cfg, policy, None, mode="continuous",
                       kv="paged")
+
+
+def test_dead_row_gives_kernel_length_zero(setup):
+    """The paged decode call site passes a length of 0 for a row whose
+    table starts at the trash page (the pool's last page): the kernel
+    walks none of its pages, so its attention adds nothing, and the live
+    rows' outputs are bit-identical to a run where that row is live."""
+    cfg, params, _, _, _ = setup
+    p = jax.tree.map(lambda a: a[0], params["stack"]["scan0"])
+    bs, nb, N = 4, 4, 9                # 8 pages plus the trash page
+    trash = N - 1
+    ks = jax.random.split(jax.random.PRNGKey(3), 3)
+    shape = (N, bs, cfg.num_kv_heads, cfg.head_dim)
+    kp = jax.random.normal(ks[0], shape).astype(jnp.bfloat16)
+    vp = jax.random.normal(ks[1], shape).astype(jnp.bfloat16)
+    x = jax.random.normal(ks[2], (3, 1, cfg.d_model)).astype(jnp.bfloat16)
+    pos = jnp.asarray([5, 9, 6], jnp.int32)
+    live = np.full((3, nb), trash, np.int32)
+    live[0, :2], live[1, :3], live[2, :2] = [0, 1], [2, 3, 4], [5, 6]
+    dead = live.copy()
+    dead[0] = trash                    # row 0: an evicted slot
+    out = {}
+    for name, tables in (("live", live), ("dead", dead)):
+        out[name], _, _ = transformer._attn_decode_paged(
+            p, x, kp, vp, pos, jnp.asarray(tables), cfg, use_pallas=True)
+    np.testing.assert_array_equal(np.asarray(out["dead"][1:]),
+                                  np.asarray(out["live"][1:]))
+    # a length-0 row attends to nothing: the residual passes through
+    np.testing.assert_array_equal(np.asarray(out["dead"][0]),
+                                  np.asarray(x[0]))
+    ref_out, _, _ = transformer._attn_decode_paged(
+        p, x, kp, vp, pos, jnp.asarray(live), cfg, use_pallas=False)
+    np.testing.assert_allclose(np.asarray(out["live"], np.float32),
+                               np.asarray(ref_out, np.float32),
+                               atol=5e-2, rtol=5e-2)
+
+
+@pytest.mark.parametrize("prefill", ["stall", "chunked"])
+def test_decode_page_share_matches_hand_count(setup, prefill):
+    """``decode_page_share`` (and the ``decode.pages`` counter) count
+    the pages the decode kernel walks: a request of cap c decodes c - 1
+    launches after its prefill token, launch g attending over
+    BUCKET + g positions, ceil((BUCKET + g) / bs) pages; dead rows walk
+    none."""
+    _, _, _, _, test = setup
+    bs = 4
+    kw = (dict(prefill="chunked", chunk_size=3, token_budget=8)
+          if prefill == "chunked" else {})
+    obs = Observability()
+    eng = _engine(setup, kv="paged", kv_block_size=bs, obs=obs, **kw)
+    res = eng.serve(_requests(test, CAPS))
+    hand = sum(-(-(BUCKET + g) // bs) for c in CAPS for g in range(1, c))
+    nb = -(-eng.max_len // bs)
+    assert obs.metrics.counters()["decode.pages"] == hand
+    walked = (res["decode_page_share"] * SLOTS * nb
+              * res["decode_dispatches"])
+    assert walked == pytest.approx(hand)
+    assert 0.0 < res["decode_page_share"] < 1.0

@@ -13,6 +13,8 @@ this one file, so that one worker loads the library.
 """
 
 import functools
+import math
+import re
 
 import pytest
 
@@ -75,6 +77,19 @@ def _has_kernel(compiled) -> bool:
 bf16, i32 = jnp.bfloat16, jnp.int32
 
 
+def _pool_relayouts(compiled) -> list:
+    """Copies and transposes in the compiled program that produce an
+    array of one KV pool's size: the pool read in another layout than
+    its own ``(N, bs, KV, D)``."""
+    n = NUM_PAGES * BS * KV * D
+    out = []
+    for line in compiled.as_text().splitlines():
+        m = re.search(r"= bf16\[([\d,]*)\]\S* (copy|transpose)\(", line)
+        if m and math.prod(int(d) for d in m.group(1).split(",")) == n:
+            out.append(line.strip()[:160])
+    return out
+
+
 def test_paged_decode_attention_compiles(one_chip):
     B = 16
     c = _compile(functools.partial(pfd.paged_flash_decode_attention,
@@ -82,6 +97,20 @@ def test_paged_decode_attention_compiles(one_chip):
                  ((B, H, D), bf16), ((NUM_PAGES, BS, KV, D), bf16),
                  ((NUM_PAGES, BS, KV, D), bf16), ((B, NB), i32), ((B,), i32))
     assert _has_kernel(c)
+
+
+def test_paged_decode_reads_pool_in_place(one_chip):
+    """At sc2-chat-steady's shapes (64 slots, 24-entry tables) the
+    kernel DMAs pages from the pools as they are: no transposed
+    ``(KV, N, bs, D)`` copy of a pool feeds it."""
+    B, nb = 64, 24
+    c = _compile(functools.partial(pfd.paged_flash_decode_attention,
+                                   interpret=False), one_chip,
+                 ((B, H, D), bf16), ((NUM_PAGES, BS, KV, D), bf16),
+                 ((NUM_PAGES, BS, KV, D), bf16), ((B, nb), i32), ((B,), i32))
+    assert _has_kernel(c)
+    assert f"bf16[{KV},{NUM_PAGES},{BS},{D}]" not in c.as_text()
+    assert _pool_relayouts(c) == []
 
 
 def test_chunked_prefill_attention_compiles(one_chip):
@@ -144,6 +173,8 @@ def test_full_width_step_compiles_and_fits(one_chip, monkeypatch, step):
         c = fn.lower(params, cache, {"tokens": arg((1, 512))}, arg((512,)),
                      arg((1, 4)), arg((1, NB)), chunk_pad=512).compile()
     assert _has_kernel(c)
+    if step == "decode_window":
+        assert _pool_relayouts(c) == []
     m = c.memory_analysis()
     assert (m.argument_size_in_bytes + m.output_size_in_bytes
             + m.temp_size_in_bytes) < HBM_BYTES
