@@ -1,9 +1,11 @@
 """Device readings shared by the per-layer readers: the peak table, and
-each launch kind's device time and kernel time from the reduced trace.
+each launch kind's device time and a named kernel's time within it from
+the reduced trace.
 
 A launch kind is matched by a part of the executable's name:
 ``ragged`` for the fused chunked prefill, ``decode`` for the decode
-window.  A reading with nothing to read returns None."""
+window.  A kernel is matched by its name (``pl.pallas_call(name=...)``).
+A reading with nothing to read returns None."""
 
 from __future__ import annotations
 
@@ -36,9 +38,12 @@ def exec_s(run, part: str) -> Optional[float]:
     return s or None
 
 
-def kernel_s(run, part: str) -> Optional[float]:
+def kernel_s(run, part: str, kernel: str) -> Optional[float]:
+    """Device time of the kernel called ``kernel`` in ``part``'s
+    launches."""
     t = run.trace_summary
-    s = _sum(t["kernel_s"], part) if t else 0.0
+    s = sum(d.get(kernel, 0.0) for k, d in t["kernel_by_name"].items()
+            if PART[part] in k) if t else 0.0
     return s or None
 
 
@@ -51,13 +56,15 @@ def mfu(run, part: str) -> Optional[float]:
     return 100.0 * run.work[part].flops / t / peaks(run.device["kind"])["flops_per_s"]
 
 
-def roofline(run, part: str) -> Optional[float]:
-    """The kernel's least time at the peaks over its measured device
-    time, as a percentage."""
-    t = kernel_s(run, part)
-    if t is None or not run.work[part].kernel_ops:
+def roofline(run, part: str, kernel: str,
+             work: Optional[str] = None) -> Optional[float]:
+    """The least time at the peaks of the kernel's work (``run.work``'s
+    entry ``work``, by default ``part``'s) over the device time of the
+    kernel called ``kernel`` in ``part``'s launches, as a percentage."""
+    t = kernel_s(run, part, kernel)
+    if t is None or not run.work[work or part].kernel_ops:
         return None
     p = peaks(run.device["kind"])
-    least, _ = flops.roofline_s(run.work[part], p["flops_per_s"],
+    least, _ = flops.roofline_s(run.work[work or part], p["flops_per_s"],
                                 p["bytes_per_s"])
     return 100.0 * least / t

@@ -8,7 +8,11 @@ mix or metric is found by name under ``rtbench/``:
   * ``cells/<workload>.json``   rate, engine sizes, the check's sample
                                 and the limits of ``correct``;
   * ``configs/<config>.json``   the model as it is run, and the name of
-                                its plain reference (``reference/``);
+                                its plain reference;
+  * ``reference/<name>.py``     that reference: the forward pass that
+                                decides ``correct``, the spread of each
+                                weight it interprets, and optionally
+                                the work of a run (``reference/__init__``);
   * ``traffic/<mix>.json``      the mix, read by ``gen.py``;
   * ``metrics/<metric>.py``     one reader per metric of BENCHMARK.json.
 
@@ -51,6 +55,7 @@ class Cell:
     config: Dict                 # configs/<config>.json
     mix: Dict                    # traffic/<mix>.json
     knobs: Dict                  # cells/<workload>.json
+    here: Path = HERE            # the directory the files were found in
 
 
 def _json(path: Path) -> Dict:
@@ -71,7 +76,7 @@ def load_cell(name: str, bench: Dict, here: Path = HERE) -> Cell:
     return Cell(name=name, entry=entry,
                 config=_json(here / "configs" / f"{entry['config']}.json"),
                 mix=_json(here / "traffic" / f"{entry['traffic']}.json"),
-                knobs=_json(here / "cells" / f"{name}.json"))
+                knobs=_json(here / "cells" / f"{name}.json"), here=here)
 
 
 def metric_entries(bench: Dict, workload: str, trace: bool) -> List[Dict]:
@@ -107,8 +112,14 @@ def reference(name: str, here: Path = HERE):
 
 
 def model_config(c: Dict):
-    """The program's ModelConfig for a configuration file.  What the
-    program cannot express is refused here, not run as something else."""
+    """The program's ModelConfig for a configuration file: the program's
+    own translation, ``repro.configs.from_published``, where it has one,
+    and else the mapping below, of the dense stacks its paged path runs.
+    What the program cannot express is refused, not run as something
+    else."""
+    from repro import configs
+    if hasattr(configs, "from_published"):
+        return configs.from_published(c)
     from repro.configs import ModelConfig
     acts = {"gelu_pytorch_tanh": "gelu", "relu": "relu"}
     if (c["hidden_act"] not in acts or c.get("mlp_gated")
@@ -287,6 +298,10 @@ class Run:
         self.log, self.require_tpu = log, require_tpu
         self.setup_parts: Dict[str, float] = {}
 
+    def reference(self):
+        """The configuration's plain reference module."""
+        return reference(self.cell.config["reference"], self.cell.here)
+
     # -- set-up -----------------------------------------------------------
     def setup(self) -> None:
         import jax
@@ -304,7 +319,9 @@ class Run:
             raise ValueError(f"a request asks for {too_long} tokens, the "
                              f"cell's engine caps {eng['max_new_tokens']}")
         self.cfg = model_config(self.cell.config)
-        self.params = weights.make(self.cfg, self.seed)
+        ref = self.reference()
+        self.params = weights.make(self.cfg, self.seed, ref.SCALES,
+                                   getattr(ref, "EMBED_SCALE", None))
         jax.block_until_ready(self.params)
         self.setup_parts["weights_s"] = time.perf_counter() - t
 
@@ -408,15 +425,12 @@ class Run:
                            if r.queue_wait_s >= 0]
         self.tokens = sum(len(r.out_tokens) for r in self.served)
         self.ids = [gen.hash_ids(r.text, V, S) for r in self.served]
-        shape = flops.Shape.from_config(self.cell.config)
         cached = cached_upper(self.ids)
         self.cached_total = int(self.res.get("cached_tokens_reused", 0))
         self.cached_bound = int(sum(cached))
-        self.work = {
-            "prefill": flops.prefill_work(shape, [(S, c) for c in cached]),
-            "decode": flops.decode_work(
-                shape, [(S, len(r.out_tokens)) for r in self.served]),
-        }
+        self.work = work(self.reference(), self.cell.config,
+                         [(S, c) for c in cached],
+                         [(S, len(r.out_tokens)) for r in self.served])
 
     # -- correctness --------------------------------------------------------
     def check(self) -> Dict[str, Dict]:
@@ -431,7 +445,7 @@ class Run:
         # the program's state goes before the reference runs
         self.engine = None
         gc.collect()
-        ref = reference(self.cell.config["reference"])
+        ref = self.reference()
         spec = ref.Spec.from_config(self.cell.config)
         gaps, nan = [], 0
         for i in sample:
@@ -472,7 +486,7 @@ class Run:
         the token that the reference computed in float8 puts first, and
         how far its float32 logit lies below the float32 best."""
         import jax.numpy as jnp
-        ref = reference(self.cell.config["reference"])
+        ref = self.reference()
         spec = ref.Spec.from_config(self.cell.config)
         k = self.cell.knobs["engine"]
         kw = dict(spec=spec, first=k["input_bucket"] - 1,
@@ -504,6 +518,26 @@ class Run:
             out.append(int(i))
             n += len(self.served[i].out_tokens)
         return out
+
+
+def work(ref, config: Dict, prefill, decode) -> Dict[str, flops.Work]:
+    """The useful work of a run by name: the prefill of ``prefill``
+    ``(prompt_len, cached)`` and the decode of ``decode`` ``(prompt_len,
+    tokens_out)``, as the reference counts them where it defines
+    ``prefill_work`` and ``decode_work``, and else as ``flops.py`` counts
+    a dense GQA stack.  Each of those gives a dict holding its launch
+    kind's entry (``prefill``, ``decode``) and any of its own kernels'."""
+    out: Dict[str, flops.Work] = {}
+    for kind, seqs, dense in (("prefill", prefill, flops.prefill_work),
+                              ("decode", decode, flops.decode_work)):
+        fn = getattr(ref, f"{kind}_work", None)
+        got = (fn(config, seqs) if fn else
+               {kind: dense(flops.Shape.from_config(config), seqs)})
+        if kind not in got:
+            raise ValueError(f"the reference's {kind}_work gives no "
+                             f"{kind!r} entry")
+        out.update(got)
+    return out
 
 
 def _repeat_share(rows) -> float:
@@ -542,6 +576,8 @@ def execute(run: Run, bench: Dict, log=print) -> Dict:
     run.window()
     run.derive()
     res = run.res
+    log("work: " + json.dumps({k: dataclasses.asdict(w)
+                               for k, w in run.work.items()}))
     log(f"window: {len(run.requests)} requests, {run.tokens} tokens, serve "
         f"wall {run.wall_s:.3f} s (process CPU {run.cpu_s:.3f} s), compiles "
         f"inside the window "
@@ -557,7 +593,8 @@ def execute(run: Run, bench: Dict, log=print) -> Dict:
         t = run.trace_summary
         log(f"trace: window {t['window_s']:.3f} s busy {t['busy_s']:.3f} s "
             f"executables {json.dumps(t['module_s'])} kernels "
-            f"{json.dumps(t['kernel_s'])} launches {json.dumps(t['launches'])}")
+            f"{json.dumps(t['kernel_by_name'])} launches "
+            f"{json.dumps(t['launches'])}")
     metrics = {}
     for m in metric_entries(bench, run.cell.name, run.trace):
         value = reader(m["name"]).read(run)

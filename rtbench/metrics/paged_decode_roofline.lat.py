@@ -5,4 +5,4 @@ from rtbench import device
 
 
 def read(run):
-    return device.roofline(run, "decode")
+    return device.roofline(run, "decode", "paged_decode_kernel")

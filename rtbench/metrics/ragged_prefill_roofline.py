@@ -5,4 +5,4 @@ from rtbench import device
 
 
 def read(run):
-    return device.roofline(run, "prefill")
+    return device.roofline(run, "prefill", "ragged_prefill_kernel")

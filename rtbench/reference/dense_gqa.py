@@ -8,6 +8,9 @@ causal softmax.  It reads the configuration file as it is run and the
 weights the benchmark made (``rtbench/weights.py``); it imports nothing
 of the program.
 
+``SCALES`` and ``EMBED_SCALE`` name every weight it interprets and the
+spread ``weights.make`` draws it with.
+
 It computes what the configuration file states, including the
 departures from the published models that the program shares (listed
 under ``departures`` in each configuration file): RMSNorm scaled by
@@ -33,6 +36,28 @@ from jax import lax
 
 HIGHEST = lax.Precision.HIGHEST
 F8_MAX = 448.0                        # largest finite float8_e4m3fn
+
+# leaf name -> standard deviation of its normal draw; None scales by
+# EMBED_SCALE/sqrt(d_model) (the embedding, which the program multiplies
+# by sqrt(d_model)).  Norm weights enter as (1 + w), so a small random w
+# tests that convention too.
+SCALES = {"embedding": None, "lm_head": 0.02, "wq": 0.04, "wk": 0.04,
+          "wv": 0.02, "wo": 0.02, "w_up": 0.02, "w_down": 0.02,
+          "ln1": 0.1, "ln2": 0.1, "final_ln": 0.1}
+
+# With a tied head a token's own logit is about d_model * std / rms(x)
+# standard deviations above the others.  At std 1/sqrt(d_model) and the
+# residual's rms after 30 random layers (about 10 at d_model 3072) that
+# is 5.4: the model repeats its input token forever and no logit is near
+# a tie, so no precision could change a served token.  A quarter of that
+# std puts the own logit at about 1.3 standard deviations.
+EMBED_SCALE = 0.25
+# Query and key weights are drawn at twice the others' std.  At 0.02 the
+# attention of 30 random layers is flat enough that greedy decoding falls
+# into repeating one token (98% of StarCoder2-3B's served tokens on a
+# v5e), where no logit is near a tie.  At 0.04 about half to four fifths
+# repeat, and near-ties are common enough that a float8 pass changes
+# served tokens by logit gaps ten times those of the bfloat16 program.
 
 
 @dataclasses.dataclass(frozen=True)

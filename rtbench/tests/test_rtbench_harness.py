@@ -49,6 +49,91 @@ def test_every_metric_of_the_benchmark_has_a_reader():
         harness.reference(cell.config["reference"])
 
 
+def test_every_reference_keeps_the_contract():
+    """What ``reference/__init__.py`` says a reference exports."""
+    for path in sorted((HERE / "reference").glob("[!_]*.py")):
+        ref = harness.reference(path.stem)
+        assert callable(ref.Spec.from_config), path.stem
+        assert callable(ref.score), path.stem
+        assert ref.SCALES and all(v is None or v > 0
+                                  for v in ref.SCALES.values()), path.stem
+        if None in ref.SCALES.values():
+            assert ref.EMBED_SCALE > 0, path.stem
+
+
+# A reference that a new configuration brings: the dense pass under a
+# name of its own, with its own weights table (the final norm drawn at
+# std 0, which dense_gqa draws at 0.1), its own counts of the work, one
+# entry for a kernel of its own among them, and a score that counts its
+# calls.
+STAND_IN = """
+from rtbench import flops, harness
+
+_dense = harness.reference("dense_gqa")
+Spec = _dense.Spec
+SCALES = dict(_dense.SCALES, final_ln=0.0)
+EMBED_SCALE = _dense.EMBED_SCALE
+CALLS = []
+
+
+def score(params, tokens, targets, **kw):
+    CALLS.append(int(tokens.shape[0]))
+    return _dense.score(params, tokens, targets, **kw)
+
+
+def prefill_work(config, seqs):
+    seqs = list(seqs)
+    return {"prefill": flops.Work(kernel_ops=float(len(seqs))),
+            "stand_in_kernel": flops.Work(kernel_bytes=7.0)}
+
+
+def decode_work(config, seqs):
+    return {"decode": flops.Work(matmul_flops=float(sum(n for _, n in seqs)))}
+"""
+
+
+def test_a_new_configuration_lands_as_new_files(tmp_path):
+    """A configuration, its reference and a cell placed as new files, and
+    nothing else: the run draws the reference's weights table, counts the
+    reference's work and compares with the reference's score."""
+    import numpy as np
+    from rtbench import flops
+    for sub in ("configs", "reference", "cells", "traffic"):
+        (tmp_path / sub).mkdir()
+    config = json.loads((HERE / "tests" / "data" / "tiny-gqa.json")
+                        .read_text())
+    config.update(name="tiny-stand-in", reference="stand_in_tiny")
+    (tmp_path / "configs" / "tiny-stand-in.json").write_text(
+        json.dumps(config))
+    (tmp_path / "reference" / "stand_in_tiny.py").write_text(STAND_IN)
+    shutil.copy(HERE / "traffic" / "chat_uncertain.json",
+                tmp_path / "traffic" / "chat_stand_in.json")
+    (tmp_path / "cells" / "tiny-stand-in-chat.json").write_text(json.dumps({
+        "rate_per_s": 3.0, "policy": "up+c",
+        "engine": {"input_bucket": 32, "chunk_size": 32, "num_slots": 4,
+                   "kv_num_blocks": 128, "max_new_tokens": 352},
+        "check_tokens": 300, "limits": {"served_logit_gap": 0.02}}))
+    bench = {"workloads": [{"name": "tiny-stand-in-chat",
+                            "config": "tiny-stand-in",
+                            "traffic": "chat_stand_in", "chips": 1}],
+             "end_to_end": [], "per_layer": []}
+    cell = harness.load_cell("tiny-stand-in-chat", bench, here=tmp_path)
+    run = harness.Run(cell, 2**31 + 9, 1.0, False, t_start=0.0,
+                      out_dir=tmp_path, log=lambda m: None,
+                      require_tpu=False)
+    result = harness.execute(run, bench, log=lambda m: None)
+    ref = harness.reference("stand_in_tiny", here=tmp_path)
+    assert run.reference() is ref
+    assert not np.asarray(run.params["final_ln"]).any()
+    assert np.asarray(run.params["stack"]["scan0"]["ln1"]).any()
+    assert run.work == {
+        "prefill": flops.Work(kernel_ops=float(len(run.requests))),
+        "stand_in_kernel": flops.Work(kernel_bytes=7.0),
+        "decode": flops.Work(matmul_flops=float(run.tokens))}
+    assert len(ref.CALLS) == len(run.sampled) > 0
+    assert result["correct"], result["checks"]
+
+
 def _run_cmd(cwd, env_extra=None):
     env = dict(os.environ, JAX_PLATFORMS="cpu")
     env.pop("PYTHONPATH", None)

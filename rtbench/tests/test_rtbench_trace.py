@@ -38,6 +38,9 @@ def test_reduce_by_hand():
                              "ragged_prefill_fn": 1}
     assert r["kernel_s"] == pytest.approx(
         {"paged_decode_steps_fn": 20e-9, "ragged_prefill_fn": 10e-9})
+    assert r["kernel_by_name"] == {
+        "paged_decode_steps_fn": {"closed_call": pytest.approx(20e-9)},
+        "ragged_prefill_fn": {"closed_call": pytest.approx(10e-9)}}
     ops = dict(r["device_ops"])
     # the loop's own time is what its nested operations leave
     assert ops["paged_decode_steps_fn:while.1"] == pytest.approx(20e-9)
@@ -90,11 +93,15 @@ def test_roofline_and_mfu_by_hand():
         device={"kind": "TPU v5 lite"}, work={"decode": w,
                                               "prefill": flops.Work()},
         trace_summary={"module_s": {"paged_decode_steps_fn": 0.01},
-                       "kernel_s": {"paged_decode_steps_fn": 0.005}})
+                       "kernel_by_name": {"paged_decode_steps_fn": {
+                           "paged_decode_kernel": 0.005}}})
     assert device.mfu(run, "decode") == pytest.approx(50.0)
-    assert device.roofline(run, "decode") == pytest.approx(50.0)
+    assert device.roofline(run, "decode", "paged_decode_kernel") == \
+        pytest.approx(50.0)
     assert device.mfu(run, "prefill") is None
-    assert device.roofline(run, "prefill") is None
+    assert device.roofline(run, "prefill", "ragged_prefill_kernel") is None
+    # a kernel the launches do not hold has nothing to read
+    assert device.roofline(run, "decode", "other_kernel") is None
     run.trace_summary = None
     assert device.mfu(run, "decode") is None
 
@@ -144,4 +151,65 @@ def test_recorded_trace():
         kernel_bytes=0.5 * peak["bytes_per_s"] * 846135e-9,
         kernel_ops=1.0)}
     assert device.mfu(run, "decode") == pytest.approx(1.0, rel=1e-6)
-    assert device.roofline(run, "decode") == pytest.approx(50.0)
+    assert device.roofline(run, "decode", "closed_call") == \
+        pytest.approx(50.0)
+
+
+@pytest.mark.parametrize("name,kernels", [
+    ("trace.json", {"ragged_prefill_fn": ["closed_call"],
+                    "paged_decode_steps_fn": ["closed_call"]}),
+    ("trace_spans.json", {"ragged_prefill_fn": ["ragged_prefill_kernel"],
+                          "paged_decode_steps_fn": ["paged_decode_kernel"]}),
+])
+def test_recorded_traces_time_kernels_by_name(name, kernels):
+    """On the chip traces, each launch kind holds one kernel, whose time
+    by name is the kind's kernel time to the last digit."""
+    fx = json.loads((DATA / name).read_text())
+    tr = trace_reduce.Trace(*(list(map(tuple, fx[k]))
+                              for k in ("ops", "modules", "host")))
+    r = trace_reduce.reduce(tr, tuple(fx["window"]))
+    assert {k: sorted(d) for k, d in r["kernel_by_name"].items()} == kernels
+    for kind, d in r["kernel_by_name"].items():
+        assert sum(d.values()) == r["kernel_s"][kind]
+
+
+def _two_kernel_trace():
+    """A decode launch that runs two kernels, and a prefill launch."""
+    def k(name, shape):
+        return f"%{name} = bf16[{shape}] " + K
+    ops = [(k("paged_decode_kernel.3", 4), 100, 20),
+           ("%fusion.1 = bf16[4] fusion(a)", 120, 10),
+           (k("mla_decode_kernel.5", 4), 130, 15),
+           (k("paged_decode_kernel.3", 4), 150, 5),
+           (k("ragged_prefill_kernel.7", 8), 200, 30)]
+    modules = [("jit_paged_decode_steps_fn(11)", 100, 60),
+               ("jit_ragged_prefill_fn(22)", 200, 40)]
+    return trace_reduce.Trace(ops=ops, modules=modules,
+                              host=[("rtbench:window", 90, 200)])
+
+
+def test_two_kernels_in_one_launch_are_timed_apart():
+    r = trace_reduce.reduce(_two_kernel_trace(), (90, 290))
+    assert r["kernel_s"] == pytest.approx(
+        {"paged_decode_steps_fn": 40e-9, "ragged_prefill_fn": 30e-9})
+    assert r["kernel_by_name"] == {
+        "paged_decode_steps_fn": {"paged_decode_kernel": pytest.approx(25e-9),
+                                  "mla_decode_kernel": pytest.approx(15e-9)},
+        "ragged_prefill_fn": {"ragged_prefill_kernel": pytest.approx(30e-9)}}
+    # work that would take half of the paged kernel's own time at the
+    # bandwidth peak, and a quarter of the other kernel's
+    peak = device.peaks("TPU v5 lite")["bytes_per_s"]
+    run = SimpleNamespace(
+        trace_summary=r, device={"kind": "TPU v5 lite"},
+        work={"decode": flops.Work(kernel_ops=1.0,
+                                   kernel_bytes=0.5 * peak * 25e-9),
+              "mla_decode": flops.Work(kernel_ops=1.0,
+                                       kernel_bytes=0.25 * peak * 15e-9),
+              "prefill": flops.Work(kernel_ops=1.0,
+                                    kernel_bytes=0.1 * peak * 30e-9)})
+    read = harness.reader("paged_decode_roofline.lat").read(run)
+    assert read == pytest.approx(50.0)
+    assert device.roofline(run, "decode", "mla_decode_kernel",
+                           work="mla_decode") == pytest.approx(25.0)
+    assert harness.reader("ragged_prefill_roofline").read(run) == \
+        pytest.approx(10.0)

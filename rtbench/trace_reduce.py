@@ -7,9 +7,10 @@ has a line of executables (``XLA Modules``: one event per launch, named
 Ops``: nested, a loop's event spans the operations inside it).  A Pallas
 kernel is an operation whose text names ``custom_call_target=
 "tpu_custom_call"``; it belongs to the executable whose launch contains
-it.  Host planes hold the program's ``TraceAnnotation`` spans (for
-example ``dispatch:<kind>``), which name the host work during each gap
-in which the device is idle.
+it, and is named by its operation's name less the ``.<n>`` that XLA
+appends (``pl.pallas_call(name=...)``).  Host planes hold the program's
+``TraceAnnotation`` spans (for example ``dispatch:<kind>``), which name
+the host work during each gap in which the device is idle.
 
 ``load`` reads a trace into plain event lists; ``reduce`` works on those
 lists only, so a test can hand it events made up by hand.
@@ -22,6 +23,7 @@ import collections
 import dataclasses
 import glob
 import os
+import re
 from typing import Dict, List, Optional, Tuple
 
 KERNEL_MARK = 'custom_call_target="tpu_custom_call"'
@@ -75,8 +77,17 @@ def module_kind(name: str) -> str:
 def op_label(name: str) -> str:
     """``%fusion.110 = bf16[...] fusion(...)`` -> ``fusion.110``, with
     ``[kernel]`` appended for a Pallas kernel."""
-    head = name.split(" = ")[0].lstrip("%").strip()
-    return head + (" [kernel]" if KERNEL_MARK in name else "")
+    return _head(name) + (" [kernel]" if KERNEL_MARK in name else "")
+
+
+def kernel_name(name: str) -> str:
+    """``%paged_decode_kernel.12 = ... custom-call(...)`` ->
+    ``paged_decode_kernel``."""
+    return re.sub(r"\.\d+$", "", _head(name))
+
+
+def _head(name: str) -> str:
+    return name.split(" = ")[0].lstrip("%").strip()
 
 
 def _clip(ev: Event, lo: float, hi: float) -> Optional[Tuple[float, float]]:
@@ -124,8 +135,9 @@ def _self_times(ops: List[Event]) -> Dict[int, float]:
 
 
 def reduce(tr: Trace, window: Tuple[float, float], top: int = 10) -> Dict:
-    """Busy and idle time, device time per executable kind and per
-    kernel, and the breakdown, all inside ``window`` (start, end ns)."""
+    """Busy and idle time, device time per executable kind, of its
+    kernels together and of each kernel by name, and the breakdown, all
+    inside ``window`` (start, end ns)."""
     lo, hi = window
     busy = _union(filter(None, (_clip(e, lo, hi) for e in tr.ops)))
     busy_ns = sum(e - s for s, e in busy)
@@ -138,6 +150,8 @@ def reduce(tr: Trace, window: Tuple[float, float], top: int = 10) -> Dict:
             launches[module_kind(m[0])] += 1
     owner = _owner(tr.modules)
     kernels: Dict[str, float] = collections.defaultdict(float)
+    by_name: Dict[str, Dict[str, float]] = collections.defaultdict(
+        lambda: collections.defaultdict(float))
     by_op: Dict[str, float] = collections.defaultdict(float)
     inside = [i for i, e in enumerate(tr.ops) if _clip(e, lo, hi)]
     sub = [tr.ops[i] for i in inside]
@@ -146,6 +160,7 @@ def reduce(tr: Trace, window: Tuple[float, float], top: int = 10) -> Dict:
         kind = owner(ev[1]) or "?"
         if KERNEL_MARK in ev[0]:
             kernels[kind] += ev[2]
+            by_name[kind][kernel_name(ev[0])] += ev[2]
         by_op[f"{kind}:{op_label(ev[0])}"] += self_t[j]
     gaps = []
     prev = lo
@@ -162,6 +177,8 @@ def reduce(tr: Trace, window: Tuple[float, float], top: int = 10) -> Dict:
         "module_s": {k: v / 1e9 for k, v in per_module.items()},
         "launches": dict(launches),
         "kernel_s": {k: v / 1e9 for k, v in kernels.items()},
+        "kernel_by_name": {k: {n: v / 1e9 for n, v in d.items()}
+                           for k, d in by_name.items()},
         "device_ops": [[k, v / 1e9] for k, v in ops_top],
         "idle_gaps": named,
     }
