@@ -33,6 +33,18 @@ class ModelConfig:
     head_dim: int = 0
     window: Optional[int] = None      # sliding-window size (tokens); None = full
     rope_theta: float = 10_000.0
+    # --- latent attention (MLA, DeepSeek-V2): kv_lora_rank > 0 turns it on.
+    # Each token caches one latent of kv_lora_rank + qk_rope_head_dim values
+    # shared by every head; num_kv_heads/head_dim are unused.
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    # --- yarn rope scaling (rope_factor 0: plain rope) ---
+    rope_factor: float = 0.0
+    rope_original_max_pos: int = 0
+    rope_mscale: float = 1.0
+    rope_mscale_all_dim: float = 0.0
     # --- mlp ---
     d_ff: int = 0
     mlp_act: str = "swiglu"           # swiglu | gelu | relu
@@ -42,7 +54,8 @@ class ModelConfig:
     moe_d_ff: int = 0
     num_shared_experts: int = 0
     num_dense_layers: int = 0         # leading dense layers before MoE stack
-    capacity_factor: float = 1.25
+    norm_topk_prob: bool = True       # renormalise the top-k gates to sum 1
+    capacity_factor: float = 1.25     # training dispatch; serving drops none
     router_aux_weight: float = 0.01
     router_z_weight: float = 1e-3
     # --- SSM (Mamba-2 / SSD) ---
@@ -83,6 +96,22 @@ class ModelConfig:
         return _round_up(self.vocab_size, 2048)
 
     @property
+    def mla(self) -> bool:
+        return self.kv_lora_rank > 0
+
+    @property
+    def latent_dim(self) -> int:
+        """Values one MLA token caches a layer: latent plus rope key."""
+        return self.kv_lora_rank + self.qk_rope_head_dim
+
+    @property
+    def latent_lanes(self) -> int:
+        """The latent as the cache stores it: padded with zeros to whole
+        128-lane tiles (576 -> 640), the width the TPU's memory layout
+        gives the pool anyway, so a page is DMA'd whole."""
+        return _round_up(self.latent_dim, 128)
+
+    @property
     def attn_groups(self) -> int:
         return self.num_heads // max(self.num_kv_heads, 1)
 
@@ -114,6 +143,12 @@ class ModelConfig:
             n += V * D
         per_attn = D * self.num_heads * self.head_dim * 2 + \
             D * self.num_kv_heads * self.head_dim * 2
+        if self.mla:
+            H, R = self.num_heads, self.kv_lora_rank
+            qk = self.qk_nope_head_dim + self.qk_rope_head_dim
+            per_attn = (D * H * qk + D * self.latent_dim + R
+                        + R * H * (self.qk_nope_head_dim + self.v_head_dim)
+                        + H * self.v_head_dim * D)
         if self.family == "ssm":
             di, nheads, ns = self.ssm_d_inner, self.ssm_heads, self.ssm_state
             cw, g = self.ssm_conv_width, self.ssm_groups

@@ -10,10 +10,12 @@ same contract and are validated against ``repro.kernels.ref`` oracles.
 from __future__ import annotations
 
 import functools
+import math
 from typing import Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import lax
 
 from repro.sharding import context as shctx
@@ -58,13 +60,61 @@ def rope_freqs(head_dim: int, theta: float) -> Array:
                             / head_dim))
 
 
-def apply_rope(x: Array, positions: Array, theta: float = 10_000.0) -> Array:
-    """x: (..., S, H, D); positions: broadcastable to (..., S)."""
+def yarn_get_mscale(scale: float, mscale: float = 1.0) -> float:
+    """Yarn's attention-temperature factor (DeepSeek-V2's formula)."""
+    return 1.0 if scale <= 1 else 0.1 * mscale * math.log(scale) + 1.0
+
+
+YARN_BETA_FAST, YARN_BETA_SLOW = 32, 1
+
+
+def yarn_freqs(dim: int, theta: float, factor: float,
+               original_max: int) -> np.ndarray:
+    """Yarn rope frequencies (arXiv:2309.00071, as DeepSeek-V2 computes
+    them): dimensions that rotate more than 32 times (beta_fast) over
+    the original context keep their frequency, those under once
+    (beta_slow) are interpolated by ``factor``, and a linear ramp blends
+    between."""
+    def corr_dim(rot):
+        return (dim * math.log(original_max / (rot * 2 * math.pi))
+                / (2 * math.log(theta)))
+
+    lo = max(math.floor(corr_dim(YARN_BETA_FAST)), 0)
+    hi = min(math.ceil(corr_dim(YARN_BETA_SLOW)), dim - 1)
+    if lo == hi:
+        hi += 0.001
+    extra = 1.0 / theta ** (np.arange(0, dim, 2, dtype=np.float32) / dim)
+    inter = extra / factor
+    ramp = np.clip((np.arange(dim // 2, dtype=np.float32) - lo) / (hi - lo),
+                   0.0, 1.0)
+    return (inter * ramp + extra * (1.0 - ramp)).astype(np.float32)
+
+
+def rope_for(cfg, dim: int):
+    """(frequencies, cos/sin scale) of a config's rope over ``dim``
+    dims: plain, or yarn where ``cfg.rope_factor`` is set."""
+    if not cfg.rope_factor:
+        return rope_freqs(dim, cfg.rope_theta), 1.0
+    f = cfg.rope_factor
+    return (jnp.asarray(yarn_freqs(dim, cfg.rope_theta, f,
+                                   cfg.rope_original_max_pos)),
+            yarn_get_mscale(f, cfg.rope_mscale)
+            / yarn_get_mscale(f, cfg.rope_mscale_all_dim))
+
+
+def apply_rope(x: Array, positions: Array, theta: float = 10_000.0, *,
+               freqs: Optional[Array] = None, mscale: float = 1.0) -> Array:
+    """x: (..., S, H, D); positions: broadcastable to (..., S).
+    ``freqs`` (D/2,) replaces the plain frequencies of ``theta`` (yarn:
+    ``rope_for``), and ``mscale`` scales cos and sin."""
     d = x.shape[-1]
-    freqs = rope_freqs(d, theta)                       # (D/2,)
+    if freqs is None:
+        freqs = rope_freqs(d, theta)                   # (D/2,)
     angles = positions[..., None].astype(jnp.float32) * freqs  # (..., S, D/2)
     angles = angles[..., None, :]                      # (..., S, 1, D/2)
     cos, sin = jnp.cos(angles), jnp.sin(angles)
+    if mscale != 1.0:
+        cos, sin = cos * mscale, sin * mscale
     x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
     out = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
     return out.astype(x.dtype)

@@ -270,13 +270,17 @@ def decode_step_paged(params: dict, cfg, cache: dict, token: Array,
     x = layers.embed(params["embed"], token, cfg)
     x = shctx.constrain(x, ("batch", None, None))
     ctx = {"pos": cache["pos"], "tables": tables, "use_pallas": use_pallas}
-    x, new_cache, _ = transformer.apply_stack(
+    x, new_cache, aux = transformer.apply_stack(
         params["stack"], x, ctx, cfg, cache=cache, mode="decode")
     x = layers.rms_norm(x, params["final_ln"], cfg.norm_eps)
     logits = layers.logits(params["embed"], x, cfg)[:, 0]
     logits = logits.astype(jnp.float32)
     next_token = jnp.argmax(logits, axis=-1).astype(jnp.int32)[:, None]
     new_cache["pos"] = cache["pos"] + 1
+    if "moe_count" in cache:
+        # the serve reads it once, at its end: no sync in the loop
+        new_cache["moe_count"] = cache["moe_count"] + jnp.stack(
+            [aux["moe_pairs"], aux["moe_experts"]]).astype(jnp.int32)
     return next_token, logits, new_cache
 
 

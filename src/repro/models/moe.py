@@ -1,4 +1,6 @@
-"""Mixture-of-Experts layer: top-k router + sort-based capacity dispatch.
+"""Mixture-of-Experts layer: top-k router + sort-based capacity dispatch
+for training, and a dropless grouped-matmul layer for serving
+(``apply_moe_dropless``).
 
 TPU-idiomatic design notes:
   * dispatch is sort-based (argsort by expert id + rank-within-expert
@@ -18,6 +20,8 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import PartitionSpec as P
 
+from repro.kernels import moe_gmm
+from repro.kernels import ops as kernel_ops
 from repro.sharding import context as shctx
 
 from . import layers
@@ -46,6 +50,10 @@ def _capacity(num_tokens: int, cfg) -> int:
     return max(8, -(-cap // 8) * 8)
 
 
+# the serving layer's aux: no loss, nothing dropped
+NO_DROP = {"moe_aux_loss": jnp.float32(0.0), "moe_drop_frac": jnp.float32(0.0)}
+
+
 def apply_moe(params: dict, x: Array, cfg) -> tuple[Array, dict]:
     """Dispatcher: expert-parallel shard_map path when a sharding policy is
     active (distributed runs), single-device reference path otherwise."""
@@ -53,6 +61,98 @@ def apply_moe(params: dict, x: Array, cfg) -> tuple[Array, dict]:
     if policy is not None:
         return apply_moe_ep(params, x, cfg, policy)
     return apply_moe_local(params, x, cfg)
+
+
+def route(params: dict, xt: Array, cfg) -> tuple[Array, Array]:
+    """Router in float32: (top-k gates (T, K), top-k experts (T, K)).
+    Softmax then greedy top-k; the gates are
+    renormalised only where ``cfg.norm_topk_prob``."""
+    logits = jnp.dot(xt.astype(jnp.float32),
+                     params["router"].astype(jnp.float32),
+                     precision=lax.Precision.HIGHEST)
+    probs = jax.nn.softmax(logits, axis=-1)
+    top_p, top_e = lax.top_k(probs, cfg.experts_per_token)
+    if cfg.norm_topk_prob:
+        top_p = top_p / jnp.sum(top_p, axis=-1, keepdims=True)
+    return top_p, top_e
+
+
+def grouped_matmul(lhs: Array, rhs: Array, group_sizes: Array,
+                   use_pallas: bool = False, layer=None) -> Array:
+    """Rows of ``lhs`` sorted by group times their group's ``rhs``
+    (``rhs[layer]`` of a layer stack where ``layer`` is given): the
+    Pallas ``moe_gmm`` kernel, or ``lax.ragged_dot``."""
+    if use_pallas:
+        return moe_gmm.gmm(lhs, rhs, group_sizes, layer=layer,
+                           interpret=kernel_ops._default_interpret())
+    if layer is not None:
+        rhs = rhs[layer]
+    return lax.ragged_dot(lhs, rhs, group_sizes,
+                          preferred_element_type=jnp.float32
+                          ).astype(lhs.dtype)
+
+
+def _experts_here(xt: Array, live, router, wg, wu, wd, cfg, e0, n_here,
+                  use_pallas: bool = False, layer=None):
+    """Every routed copy of the rows ``xt`` (T, D) that goes to one of
+    the experts ``e0 .. e0 + n_here - 1``, whose weights ``wg``/``wu``/
+    ``wd`` are, through that expert: (output (T, D) float32, the group
+    size of each of those experts).  No capacity: the copies are sorted
+    by expert and each expert multiplies exactly its own group
+    (``grouped_matmul``); copies bound elsewhere, and every copy of a row
+    that ``live`` (T,) marks False, sort after the last group, into none."""
+    T, D = xt.shape
+    K = cfg.experts_per_token
+    top_p, top_e = route({"router": router}, xt, cfg)
+    here = top_e.reshape(-1) - e0
+    mine = (here >= 0) & (here < n_here)
+    if live is not None:
+        mine = mine & jnp.repeat(live, K)
+    here = jnp.where(mine, here, n_here)
+    order = jnp.argsort(here, stable=True)
+    sorted_tok = order // K
+    group_sizes = jnp.bincount(here, length=n_here + 1)[:n_here].astype(
+        jnp.int32)
+    xs = xt[sorted_tok]
+    h = jax.nn.silu(grouped_matmul(xs, wg, group_sizes, use_pallas,
+                                   layer)) * \
+        grouped_matmul(xs, wu, group_sizes, use_pallas, layer)
+    ys = grouped_matmul(h, wd, group_sizes, use_pallas, layer)
+    w = jnp.where(mine[order], top_p.reshape(-1)[order], 0.0)
+    out = jnp.zeros((T, D), jnp.float32).at[sorted_tok].add(
+        ys.astype(jnp.float32) * w[:, None])
+    return out, group_sizes
+
+
+def apply_moe_dropless(params: dict, x: Array, cfg, valid=None,
+                       use_pallas: bool = False,
+                       layer=None) -> tuple[Array, dict]:
+    """The serving expert layer: every routed token reaches its experts
+    (``_experts_here``), on one device or, where a sharding policy is
+    active, on the expert shards (``apply_moe_ep(dropless=True)``).
+
+    x: (B, S, D); valid: optional (B, S) bool, False rows (padding,
+    dead decode slots) are routed nowhere and get only the shared
+    experts' output.  ``layer``: the experts' weights are a layer stack
+    (``transformer.apply_stack``) and this is the layer's index.  On one
+    device the aux dict counts the routed token-expert pairs
+    (``moe_pairs``) and the experts they reached (``moe_experts``)."""
+    policy = shctx.current()
+    if policy is not None:
+        return apply_moe_ep(params, x, cfg, policy, dropless=True,
+                            valid=valid)
+    B, S, D = x.shape
+    xt = x.reshape(B * S, D)
+    live = None if valid is None else valid.reshape(-1)
+    out, group_sizes = _experts_here(
+        xt, live, params["router"], params["w_gate"], params["w_up"],
+        params["w_down"], cfg, 0, cfg.num_experts, use_pallas, layer)
+    if cfg.num_shared_experts:
+        out = out + layers.apply_mlp(params["shared"], xt,
+                                     "swiglu").astype(jnp.float32)
+    aux = dict(NO_DROP, moe_pairs=jnp.sum(group_sizes),
+               moe_experts=jnp.sum(group_sizes > 0).astype(jnp.int32))
+    return out.astype(x.dtype).reshape(B, S, D), aux
 
 
 def apply_moe_local(params: dict, x: Array, cfg) -> tuple[Array, dict]:
@@ -70,7 +170,8 @@ def apply_moe_local(params: dict, x: Array, cfg) -> tuple[Array, dict]:
     router_logits = xt.astype(jnp.float32) @ params["router"]       # (T, E)
     probs = jax.nn.softmax(router_logits, axis=-1)
     top_p, top_e = lax.top_k(probs, K)                              # (T, K)
-    top_p = top_p / jnp.sum(top_p, axis=-1, keepdims=True)          # renorm
+    if cfg.norm_topk_prob:
+        top_p = top_p / jnp.sum(top_p, axis=-1, keepdims=True)      # renorm
 
     # ---- sort-based dispatch -------------------------------------------
     flat_e = top_e.reshape(-1)                                      # (T*K,)
@@ -136,6 +237,10 @@ def apply_moe_local(params: dict, x: Array, cfg) -> tuple[Array, dict]:
 # axis) every model shard keeps all E experts but shards the expert d_ff
 # ("mlp" -> "model"); the same closing psum then completes the partial
 # w_down contraction instead.  Both cases are one code path below.
+#
+# Serving (``dropless=True``) keeps both layouts and their closing
+# reductions, and replaces the capacity dispatch by ``_experts_here``:
+# each shard's experts take every copy routed to them.
 
 
 def _axes_tuple(r):
@@ -144,7 +249,8 @@ def _axes_tuple(r):
     return (r,) if isinstance(r, str) else tuple(r)
 
 
-def apply_moe_ep(params: dict, x: Array, cfg, policy) -> tuple[Array, dict]:
+def apply_moe_ep(params: dict, x: Array, cfg, policy, dropless: bool = False,
+                 valid=None) -> tuple[Array, dict]:
     mesh = policy.mesh
     B, S, D = x.shape
     E, K = cfg.num_experts, cfg.experts_per_token
@@ -165,6 +271,8 @@ def apply_moe_ep(params: dict, x: Array, cfg, policy) -> tuple[Array, dict]:
     C = _capacity(B * S if token_replicated else T_loc, cfg)
 
     x_spec = P(batch_axes if batch_axes else None, None, None)
+    if valid is None:
+        valid = jnp.ones((B, S), bool)
     wg_spec = policy.spec(params["w_gate"].shape, policy.moe_axes("gate_up"))
     wd_spec = policy.spec(params["w_down"].shape, policy.moe_axes("down"))
     router_spec = P(None, None)
@@ -173,10 +281,10 @@ def apply_moe_ep(params: dict, x: Array, cfg, policy) -> tuple[Array, dict]:
     gath_axes_g = tuple(_axes_tuple(wg_spec[2 if serving else 1]))
     gath_axes_d = tuple(_axes_tuple(wd_spec[1 if serving else 2]))
 
-    def f(xl, router, wg, wu, wd):
-        # xl: (B_loc, S, D); router: (D, E) replicated
+    def f(xl, vl, router, wg, wu, wd):
+        # xl: (B_loc, S, D); vl: (B_loc, S); router: (D, E) replicated
         if token_replicated:
-            return _f_token_replicated(xl, router, wg, wu, wd)
+            return _f_token_replicated(xl, vl, router, wg, wu, wd)
         # train/prefill: gather the expert weights' non-expert shard axis
         # (ZeRO layout: d_model; serving layout: d_ff)
         if gath_axes_g:
@@ -188,6 +296,12 @@ def apply_moe_ep(params: dict, x: Array, cfg, policy) -> tuple[Array, dict]:
             wd = lax.all_gather(wd, gath_axes_d, axis=ax, tiled=True)
         xt = xl.reshape(T_loc, D)
         e0 = (lax.axis_index("model") * E_loc) if experts_sharded else 0
+        if dropless:
+            out, _ = _experts_here(xt, vl.reshape(-1), router, wg, wu, wd,
+                                   cfg, e0, E_loc)
+            if model_sz > 1:
+                out = lax.psum(out, "model")
+            return out.astype(xl.dtype).reshape(xl.shape), NO_DROP
 
         router_logits = xt.astype(jnp.float32) @ router          # (T, E)
         probs = jax.nn.softmax(router_logits, axis=-1)
@@ -257,15 +371,21 @@ def apply_moe_ep(params: dict, x: Array, cfg, policy) -> tuple[Array, dict]:
             aux = jax.tree.map(lambda v: lax.pmean(v, batch_axes), aux)
         return out, aux
 
-    def _f_token_replicated(xl, router, wg, wu, wd):
+    def _f_token_replicated(xl, vl, router, wg, wu, wd):
         # wg/wu: (E_loc, D, F_loc); wd: (E_loc, F_loc, D) — weights stay
         # put; the (tiny) decode token set is gathered instead.
         T_all = B * S
         xt = xl.reshape(T_loc, D)
+        live = vl.reshape(T_loc)
         if batch_axes:
             xt = lax.all_gather(xt, batch_axes, axis=0,
                                 tiled=True)              # (T_all, D)
+            live = lax.all_gather(live, batch_axes, axis=0, tiled=True)
         e0 = (lax.axis_index("model") * E_loc) if experts_sharded else 0
+        if dropless:
+            out_all, _ = _experts_here(xt, live, router, wg, wu, wd, cfg,
+                                       e0, E_loc)
+            return _close_replicated(out_all, xl), NO_DROP
 
         router_logits = xt.astype(jnp.float32) @ router
         probs = jax.nn.softmax(router_logits, axis=-1)
@@ -300,19 +420,7 @@ def apply_moe_ep(params: dict, x: Array, cfg, policy) -> tuple[Array, dict]:
             (sorted_w * keep).astype(ye.dtype)[:, None]
         out_all = jnp.zeros((T_all, D), jnp.float32).at[sorted_tok].add(
             contrib.astype(jnp.float32))
-        # One reduction closes BOTH partial sums — over "model" iff the
-        # expert dim is actually partitioned there, and over exactly the
-        # axes that shard d_ff (axes where computation was identical must
-        # NOT be summed: they hold replicas, not partials).
-        f_axes = tuple(_axes_tuple(wg_spec[2]))
-        psum_axes = (("model",) if experts_sharded else ()) + f_axes
-        if psum_axes:
-            out_all = lax.psum(out_all, psum_axes)
-        idx = jnp.int32(0)
-        for a in batch_axes:
-            idx = idx * mesh.shape[a] + lax.axis_index(a)
-        out = lax.dynamic_slice_in_dim(out_all, idx * T_loc, T_loc, 0)
-        out = out.astype(xl.dtype).reshape(xl.shape)
+        out = _close_replicated(out_all, xl)
 
         me = jnp.mean(probs, axis=0)
         ce = jnp.mean(
@@ -337,13 +445,29 @@ def apply_moe_ep(params: dict, x: Array, cfg, policy) -> tuple[Array, dict]:
         }
         return out, aux
 
+    def _close_replicated(out_all, xl):
+        # One reduction closes BOTH partial sums — over "model" iff the
+        # expert dim is actually partitioned there, and over exactly the
+        # axes that shard d_ff (axes where computation was identical must
+        # NOT be summed: they hold replicas, not partials).
+        f_axes = tuple(_axes_tuple(wg_spec[2]))
+        psum_axes = (("model",) if experts_sharded else ()) + f_axes
+        if psum_axes:
+            out_all = lax.psum(out_all, psum_axes)
+        idx = jnp.int32(0)
+        for a in batch_axes:
+            idx = idx * mesh.shape[a] + lax.axis_index(a)
+        out = lax.dynamic_slice_in_dim(out_all, idx * T_loc, T_loc, 0)
+        return out.astype(xl.dtype).reshape(xl.shape)
+
     fn = jax.shard_map(
         f, mesh=mesh,
-        in_specs=(x_spec, router_spec, wg_spec, wg_spec, wd_spec),
+        in_specs=(x_spec, P(*x_spec[:2]), router_spec, wg_spec, wg_spec,
+                  wd_spec),
         out_specs=(x_spec, {"moe_aux_loss": P(), "moe_drop_frac": P()}),
         check_vma=False)
-    out, aux = fn(x, params["router"], params["w_gate"], params["w_up"],
-                  params["w_down"])
+    out, aux = fn(x, valid, params["router"], params["w_gate"],
+                  params["w_up"], params["w_down"])
     if cfg.num_shared_experts:
         xt = x.reshape(B * S, D)
         out = out + layers.apply_mlp(params["shared"], xt,

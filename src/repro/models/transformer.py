@@ -30,7 +30,7 @@ from repro.kernels import ragged_chunked_prefill as rcp_kernel
 from repro.kvcache import paged as paged_lib
 from repro.sharding import context as shctx
 
-from . import layers, moe as moe_lib, rglru, ssm
+from . import layers, mla, moe as moe_lib, rglru, ssm
 from .layers import rms_norm
 
 Array = jax.Array
@@ -45,7 +45,8 @@ def init_attn_mlp_block(key, cfg, dtype, *, use_moe=False, cross=False):
     ks = jax.random.split(key, 5)
     p = {
         "ln1": jnp.zeros((cfg.d_model,), dtype),
-        "attn": layers.init_attention(ks[0], cfg, dtype),
+        "attn": (mla.init_mla(ks[0], cfg, dtype) if cfg.mla
+                 else layers.init_attention(ks[0], cfg, dtype)),
         "ln2": jnp.zeros((cfg.d_model,), dtype),
     }
     if use_moe:
@@ -376,13 +377,31 @@ def _mlp_part(p, x, cfg):
                                                    cfg.norm_eps), cfg.mlp_act)
 
 
-def _moe_part(p, x, cfg, capacity_factor=None):
-    y, aux = moe_lib.apply_moe(p["moe"], rms_norm(x, p["ln2"], cfg.norm_eps),
-                               cfg)
+def _moe_part(p, x, cfg, *, serving=False, valid=None, use_pallas=False,
+              layer=None):
+    """The expert layer.  Serving (prefill and decode against a cache)
+    drops no token (``moe.apply_moe_dropless``), on one device or
+    sharded; training dispatches with a capacity (``moe.apply_moe``).
+    ``layer``: the experts' weights are the whole scanned stack and this
+    is the layer's index (``apply_stack``)."""
+    h = rms_norm(x, p["ln2"], cfg.norm_eps)
+    if serving:
+        y, aux = moe_lib.apply_moe_dropless(p["moe"], h, cfg, valid,
+                                            use_pallas, layer)
+    else:
+        y, aux = moe_lib.apply_moe(p["moe"], h, cfg)
     return x + y, aux
 
 
+def _ffn_part(kind, p, x, cfg, **serving):
+    """The block's second half: the expert layer or the MLP."""
+    if kind == "moe":
+        return _moe_part(p, x, cfg, **serving)
+    return _mlp_part(p, x, cfg), ZERO_AUX
+
+
 ZERO_AUX = {"moe_aux_loss": jnp.float32(0.0), "moe_drop_frac": jnp.float32(0.0)}
+EXPERT_WEIGHTS = ("w_gate", "w_up", "w_down")
 
 
 def apply_block_seq(kind, p, x, ctx, cfg, cache=None):
@@ -397,6 +416,16 @@ def apply_block_seq(kind, p, x, ctx, cfg, cache=None):
     # the residual stream (and thus every saved layer input under remat)
     # is then sequence-sharded between blocks (16x less live memory).
     x = shctx.constrain(x, ("batch", "seq", None))
+    if cfg.mla and kind in ("dense", "moe"):
+        y, lat = mla.attend_seq(p["attn"], rms_norm(x, p["ln1"], cfg.norm_eps),
+                                positions, cfg)
+        new_cache = None
+        if cache is not None:
+            nl, _, _ = prefill_write_kv(cache["ckv"], cache["ckv"], lat, lat)
+            new_cache = dict(cache, ckv=nl)
+        x, aux = _ffn_part(kind, p, x + y, cfg, serving=cache is not None,
+                           layer=ctx.get("layer"))
+        return x, new_cache, aux
     if kind in ("dense", "moe", "cross"):
         window = cfg.window if kind != "attn_local" else cfg.local_window
         x, k, v = _attn_seq(p, x, positions, cfg, window)
@@ -411,7 +440,8 @@ def apply_block_seq(kind, p, x, ctx, cfg, cache=None):
                 new_cache["enc_k"] = enc_k.astype(new_cache["enc_k"].dtype)
                 new_cache["enc_v"] = enc_v.astype(new_cache["enc_v"].dtype)
         if kind == "moe":
-            x, aux = _moe_part(p, x, cfg)
+            x, aux = _moe_part(p, x, cfg, serving=cache is not None,
+                               layer=ctx.get("layer"))
         else:
             x = _mlp_part(p, x, cfg)
         return x, new_cache, aux
@@ -442,16 +472,17 @@ def apply_block_chunk(kind, p, x, ctx, cfg, cache):
     i32, use_pallas bool).  Only the paged-eligible kinds apply
     (``paged_supported`` gates the engine to dense/moe stacks).
     """
-    aux = ZERO_AUX
     x = shctx.constrain(x, ("batch", None, None))
+    if cfg.mla:
+        raise NotImplementedError("latent attention prefills through the "
+                                  "fused ragged path (mode='chunks')")
     if kind in ("dense", "moe"):
         x, nk, nv = _attn_chunk_paged(
             p, x, cache["k"], cache["v"], ctx["positions"],
             ctx["table_row"], cfg, ctx.get("use_pallas", False))
-        if kind == "moe":
-            x, aux = _moe_part(p, x, cfg)
-        else:
-            x = _mlp_part(p, x, cfg)
+        x, aux = _ffn_part(kind, p, x, cfg, serving=True,
+                           use_pallas=ctx.get("use_pallas", False),
+                           layer=ctx.get("layer"))
         return x, dict(cache, k=nk, v=nv), aux
     raise NotImplementedError(
         f"chunked prefill requires a paged-eligible stack (got {kind!r})")
@@ -463,15 +494,20 @@ def apply_block_chunks(kind, p, x, ctx, cfg, cache):
     (``_attn_chunks_paged``).  Same kind gating as the per-chunk mode
     (``paged_supported`` restricts the engine to dense/moe stacks).
     """
-    aux = ZERO_AUX
     x = shctx.constrain(x, ("batch", None, None))
+    serving = dict(serving=True, valid=ctx["valid"][None],
+                   use_pallas=ctx.get("use_pallas", False),
+                   layer=ctx.get("layer"))
+    if cfg.mla and kind in ("dense", "moe"):
+        y, pool = mla.chunks_paged(
+            p["attn"], rms_norm(x, p["ln1"], cfg.norm_eps), cache["ckv"],
+            ctx, cfg)
+        x, aux = _ffn_part(kind, p, x + y, cfg, **serving)
+        return x, dict(cache, ckv=pool), aux
     if kind in ("dense", "moe"):
         x, nk, nv = _attn_chunks_paged(p, x, cache["k"], cache["v"],
                                        ctx, cfg)
-        if kind == "moe":
-            x, aux = _moe_part(p, x, cfg)
-        else:
-            x = _mlp_part(p, x, cfg)
+        x, aux = _ffn_part(kind, p, x, cfg, **serving)
         return x, dict(cache, k=nk, v=nv), aux
     raise NotImplementedError(
         f"chunked prefill requires a paged-eligible stack (got {kind!r})")
@@ -482,6 +518,30 @@ def apply_block_decode(kind, p, x, ctx, cfg, cache):
     tables = ctx.get("tables")         # paged decode: (B, nb) block table
     aux = ZERO_AUX
     x = shctx.constrain(x, ("batch", None, None))
+    use_pallas = ctx.get("use_pallas", False)
+    if cfg.mla and kind in ("dense", "moe"):
+        h = rms_norm(x, p["ln1"], cfg.norm_eps)
+        valid = None
+        if tables is not None:
+            y, ring = mla.decode_paged(p["attn"], h, cache["ckv"], pos,
+                                       tables, cfg, use_pallas)
+            # rows at the trash page are dead slots: routed nowhere
+            valid = (tables[:, 0] != cache["ckv"].shape[0] - 1)[:, None]
+        else:
+            q, lat = mla.project(p["attn"], h, pos[..., None], cfg)
+            ring, _ = decode_write_kv(cache["ckv"], cache["ckv"], lat, lat,
+                                      pos)
+            slot_pos = ctx["slot_pos"]
+            Wc = ring.shape[1]
+            if pos.ndim:
+                rows = jnp.arange(slot_pos.shape[0])
+                slot_pos = slot_pos.at[rows, pos % Wc].set(pos)
+            else:
+                slot_pos = slot_pos.at[pos % Wc].set(pos)
+            y = mla.attend_ring(p["attn"], q, ring, pos, slot_pos, cfg)
+        x, aux = _ffn_part(kind, p, x + y, cfg, serving=True, valid=valid,
+                           use_pallas=use_pallas, layer=ctx.get("layer"))
+        return x, dict(cache, ckv=ring), aux
     if kind in ("dense", "moe", "cross"):
         if tables is not None:
             x, nk, nv = _attn_decode_paged(p, x, cache["k"], cache["v"],
@@ -493,7 +553,8 @@ def apply_block_decode(kind, p, x, ctx, cfg, cache):
         if kind == "cross":
             x = _cross_attn(p, x, cache["enc_k"], cache["enc_v"], cfg)
         if kind == "moe":
-            x, aux = _moe_part(p, x, cfg)
+            x, aux = _moe_part(p, x, cfg, serving=True,
+                               use_pallas=use_pallas, layer=ctx.get("layer"))
         else:
             x = _mlp_part(p, x, cfg)
         return x, dict(cache, k=nk, v=nv), aux
@@ -571,7 +632,7 @@ def init_stack(key, cfg, dtype) -> dict:
 
 
 def _sum_aux(a, b):
-    return {k: a[k] + b[k] for k in a}
+    return {k: a.get(k, 0) + b.get(k, 0) for k in {**a, **b}}
 
 
 def apply_stack(params: dict, x: Array, ctx: dict, cfg, cache=None,
@@ -579,7 +640,8 @@ def apply_stack(params: dict, x: Array, ctx: dict, cfg, cache=None,
     """Run the whole block stack. Returns (x, new_cache, aux)."""
     pat, n, prefix, tail = stack_pattern(cfg)
     aux = dict(ZERO_AUX)
-    new_cache = {} if cache is not None else None
+    # entries besides the layers' (pos, counters) pass through
+    new_cache = dict(cache) if cache is not None else None
     apply_fn = {"decode": apply_block_decode,
                 "chunk": apply_block_chunk,
                 "chunks": apply_block_chunks}.get(mode, apply_block_seq)
@@ -592,13 +654,31 @@ def apply_stack(params: dict, x: Array, ctx: dict, cfg, cache=None,
             new_cache[f"prefix{i}"] = nc
 
     if n > 0:
+        p_stacked = tuple(params[f"scan{s}"] for s in range(len(pat)))
+        # The serving expert layer reads its experts' weights from the
+        # whole stack by layer index: a kernel operand sliced out of the
+        # stack inside the scan would be copied whole every layer.
+        whole = [{} for _ in pat]
+        if cache is not None and shctx.current() is None:
+            for s, kind in enumerate(pat):
+                if kind == "moe":
+                    m = p_stacked[s]["moe"]
+                    whole[s] = {k: m[k] for k in EXPERT_WEIGHTS}
+                    p_stacked = p_stacked[:s] + (dict(p_stacked[s], moe={
+                        k: v for k, v in m.items()
+                        if k not in EXPERT_WEIGHTS}),) + p_stacked[s + 1:]
+
         def superblock(x, inp):
-            ps, cs = inp
+            ps, cs, i = inp if any(whole) else inp + (None,)
             auxes = dict(ZERO_AUX)
             ncs = [None] * len(pat)
             for s, kind in enumerate(pat):
                 c = None if cs is None else cs[s]
-                x, nc, a = apply_fn(kind, ps[s], x, ctx, cfg, c)
+                p, cx = ps[s], ctx
+                if whole[s]:
+                    p = dict(p, moe=dict(p["moe"], **whole[s]))
+                    cx = dict(ctx, layer=i)
+                x, nc, a = apply_fn(kind, p, x, cx, cfg, c)
                 auxes = _sum_aux(auxes, a)
                 ncs[s] = nc
             if cs is None:
@@ -607,13 +687,14 @@ def apply_stack(params: dict, x: Array, ctx: dict, cfg, cache=None,
 
         body = jax.checkpoint(superblock) if (remat and mode == "train") \
             else superblock
-        p_stacked = tuple(params[f"scan{s}"] for s in range(len(pat)))
+        layer_idx = ((jnp.arange(n, dtype=jnp.int32),) if any(whole)
+                     else ())
         if cache is None:
-            x, auxes = lax.scan(body, x, (p_stacked, None))
+            x, auxes = lax.scan(body, x, (p_stacked, None) + layer_idx)
         else:
             c_stacked = tuple(cache[f"scan{s}"] for s in range(len(pat)))
-            x, (nc_stacked, auxes) = lax.scan(body, x,
-                                              (p_stacked, c_stacked))
+            x, (nc_stacked, auxes) = lax.scan(
+                body, x, (p_stacked, c_stacked) + layer_idx)
             for s in range(len(pat)):
                 new_cache[f"scan{s}"] = nc_stacked[s]
         aux = _sum_aux(aux, jax.tree.map(jnp.sum, auxes))
@@ -634,6 +715,9 @@ def apply_stack(params: dict, x: Array, ctx: dict, cfg, cache=None,
 
 
 def _layer_cache_zeros(kind, cfg, batch, max_len, dtype):
+    if cfg.mla and kind in ("dense", "moe"):
+        cap = kv_cache_capacity(cfg, max_len, cfg.window)
+        return {"ckv": jnp.zeros((batch, cap, cfg.latent_lanes), dtype)}
     if kind in ("dense", "moe", "cross", "attn_local"):
         window = cfg.local_window if kind == "attn_local" else cfg.window
         cap = kv_cache_capacity(cfg, max_len, window)
@@ -740,6 +824,10 @@ def init_paged_cache(cfg, num_slots: int, num_blocks: int,
     pat, n, prefix, tail = stack_pattern(cfg)
 
     def pages():
+        if cfg.mla:
+            # one latent a token shared by every head
+            return {"ckv": jnp.zeros((num_blocks, block_size,
+                                      cfg.latent_lanes), dtype)}
         return {"k": jnp.zeros((num_blocks, block_size, cfg.num_kv_heads,
                                 cfg.head_dim), dtype),
                 "v": jnp.zeros((num_blocks, block_size, cfg.num_kv_heads,
@@ -756,7 +844,15 @@ def init_paged_cache(cfg, num_slots: int, num_blocks: int,
     for i, _ in enumerate(tail):
         cache[f"tail{i}"] = pages()
     cache["pos"] = jnp.zeros((num_slots,), jnp.int32)
+    if cfg.family == "moe":
+        # decode launches' routed token-expert pairs and the experts
+        # they reached, summed over MoE layers (``model.decode_step_paged``)
+        cache["moe_count"] = jnp.zeros((2,), jnp.int32)
     return cache
+
+
+def _is_layer(key: str) -> bool:
+    return key.startswith(("prefix", "scan", "tail"))
 
 
 def write_paged(cache: dict, one: dict, slot, table_row,
@@ -773,6 +869,8 @@ def write_paged(cache: dict, one: dict, slot, table_row,
     for key, big in cache.items():
         if key == "pos":
             out[key] = big.at[slot].set(jnp.asarray(seq_len, big.dtype))
+        elif not _is_layer(key):
+            out[key] = big
         else:
             if key.startswith("scan"):
                 # leading layer axis: scatter each layer's pages with
@@ -839,7 +937,7 @@ def copy_paged_block(cache: dict, src, dst) -> dict:
     copy = lambda pg: paged_lib.copy_block(pg, src, dst)  # noqa: E731
     out = {}
     for key, big in cache.items():
-        if key == "pos":
+        if not _is_layer(key):
             out[key] = big
         elif key.startswith("scan"):
             out[key] = jax.tree.map(jax.vmap(copy), big)

@@ -733,6 +733,16 @@ class ServingEngine:
             "health_trace": (list(self.obs.health_trace)
                              if self.obs is not None else []),
         }
+        state = (self.paged_cache.state if self.paged_cache is not None
+                 else None)
+        if state is not None and "moe_count" in state:
+            # expert-layer keys, only for a paged MoE serve: the decode
+            # launches' routed token-expert pairs and the experts they
+            # reached, summed over steps and MoE layers on the device
+            # and read here once, after the serve
+            pairs, reached = (int(v) for v in np.asarray(state["moe_count"]))
+            out["moe_decode_pairs"] = pairs
+            out["moe_decode_experts"] = reached
         if self.faults is not None:
             # fault-gated keys: present ONLY when a fault plan is
             # threaded, so unfaulted result dicts stay byte-identical
@@ -917,7 +927,7 @@ class ServingEngine:
         content across serves (all decode slots were evicted at the
         previous serve's end, so only cache-pinned blocks are live) and
         the prefix index keeps its entries while its per-serve counters
-        reset."""
+        reset, the expert layer's ``moe_count`` among them."""
         C = self.num_slots
         mreg = self.obs.metrics if self.obs is not None else None
         if (self.persist_prefix_cache and self.paged_cache is not None
@@ -926,6 +936,9 @@ class ServingEngine:
             pc = self.prefix_cache
             pc.reset_stats()
             pc.metrics = mreg
+            if "moe_count" in kvc.state:
+                kvc.state = dict(kvc.state, moe_count=jnp.zeros_like(
+                    kvc.state["moe_count"]))
             return kvc, alloc, pc, kvc.state
         kvc = PagedKVCache(self.cfg, C, self.kv_num_blocks,
                            self.kv_block_size, self.max_len)

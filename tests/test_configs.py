@@ -27,6 +27,8 @@ EXPECTED = {
                                   N=(1.2e9, 2.4e9), A=None),
     "recurrentgemma-9b": dict(L=38, d=4096, H=16, KV=1, V=256000,
                               N=(8e9, 10e9), A=None),
+    "deepseek-v2-lite": dict(L=27, d=2048, H=16, KV=16, V=102400,
+                             N=(15e9, 16.5e9), A=(2.2e9, 2.9e9)),
 }
 
 

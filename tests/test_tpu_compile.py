@@ -4,7 +4,9 @@ No chip is needed: the TPU compiler compiles for a chip that is
 described and not attached, and refuses what the chip would refuse
 (unaligned slices, too much VMEM, a program that does not fit).  The
 shapes are StarCoder2-3B's published widths: 24 query heads, 2 KV
-heads, head_dim 128, d_model 3072, 16-token pages.
+heads, head_dim 128, d_model 3072, 16-token pages; and DeepSeek-V2-Lite's
+(9 of its 27 layers as the benchmark runs them): 16 heads over one
+576-value latent stored in 640 lanes, 64 experts of width 1408.
 
 The topology is described inside a fixture, never at import time: only
 one process at a time may load the TPU library, and xdist workers each
@@ -12,6 +14,7 @@ import every test file.  Keep every compile for the described chip in
 this one file, so that one worker loads the library.
 """
 
+import dataclasses
 import functools
 import math
 import re
@@ -24,6 +27,7 @@ from jax.sharding import SingleDeviceSharding
 
 from repro import configs
 from repro.kernels import chunked_prefill_attention as cpa
+from repro.kernels import mla_decode, mla_prefill, moe_gmm
 from repro.kernels import ops
 from repro.kernels import paged_decode_attention as pfd
 from repro.kernels import ragged_chunked_prefill as rcp
@@ -175,6 +179,81 @@ def test_full_width_step_compiles_and_fits(one_chip, monkeypatch, step):
     assert _has_kernel(c)
     if step == "decode_window":
         assert _pool_relayouts(c) == []
+    m = c.memory_analysis()
+    assert (m.argument_size_in_bytes + m.output_size_in_bytes
+            + m.temp_size_in_bytes) < HBM_BYTES
+
+
+# --- DeepSeek-V2-Lite: latent attention and the dropless expert layer ---
+
+LITE = dataclasses.replace(configs.get_config("deepseek-v2-lite"),
+                           num_layers=9)
+LANES, LITE_NB, ROWS = LITE.latent_lanes, 25, 64
+
+
+def test_mla_decode_attention_compiles(one_chip):
+    c = _compile(functools.partial(mla_decode.mla_decode_attention,
+                                   rank=LITE.kv_lora_rank, interpret=False),
+                 one_chip, ((ROWS, LITE.num_heads, LANES), bf16),
+                 ((NUM_PAGES, BS, LANES), bf16), ((ROWS, LITE_NB), i32),
+                 ((ROWS,), i32))
+    assert _has_kernel(c)
+
+
+@pytest.mark.parametrize("chunks,chunk_pad", [(1, 1), (8, 32), (8, 128)])
+def test_mla_prefill_attention_compiles(one_chip, chunks, chunk_pad):
+    c = _compile(functools.partial(mla_prefill.mla_prefill_attention,
+                                   rank=LITE.kv_lora_rank, interpret=False),
+                 one_chip, ((chunks, chunk_pad, LITE.num_heads, LANES), bf16),
+                 ((NUM_PAGES, BS, LANES), bf16), ((chunks, LITE_NB), i32),
+                 ((chunks, 4), i32))
+    assert _has_kernel(c)
+
+
+@pytest.mark.parametrize("rows", [ROWS * 6, 128 * 6])
+@pytest.mark.parametrize("which", ["gate_up", "down"])
+def test_moe_gmm_compiles(one_chip, rows, which):
+    D, F, E = LITE.d_model, LITE.moe_d_ff, LITE.num_experts
+    K, N = (D, F) if which == "gate_up" else (F, D)
+    c = _compile(functools.partial(moe_gmm.gmm, interpret=False), one_chip,
+                 ((rows, K), bf16), ((E, K, N), bf16), ((E,), i32))
+    assert _has_kernel(c)
+
+
+@pytest.mark.parametrize("step", ["decode_window", "ragged_prefill"])
+def test_deepseek_step_compiles_and_fits(one_chip, monkeypatch, step):
+    """DeepSeek-V2-Lite's whole step programs, 9 layers at published
+    widths and 64 slots: the latent kernel and the expert matmul are in,
+    and parameters plus the latent pool held twice fit one chip."""
+    monkeypatch.setattr(ops, "_default_interpret", lambda: False)
+    params = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        jax.eval_shape(functools.partial(
+            model_lib.init_params, jax.random.PRNGKey(0), LITE)))
+    cache = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        jax.eval_shape(functools.partial(
+            transformer.init_paged_cache, LITE, ROWS, NUM_PAGES, BS)))
+
+    def arg(shape):
+        return jax.ShapeDtypeStruct(shape, i32, sharding=one_chip)
+
+    if step == "decode_window":
+        fn = generate.make_paged_decode_steps_fn(LITE, True).fn
+        c = fn.lower(params, cache, arg((ROWS, 1)), arg((ROWS, LITE_NB)),
+                     num_steps=1).compile()
+        kernels = ("mla_decode_kernel", "moe_gmm_kernel")
+    else:
+        fn = generate.make_ragged_prefill_fn(LITE, True).fn
+        c = fn.lower(params, cache, {"tokens": arg((1, 128))}, arg((128,)),
+                     arg((8, 4)), arg((8, LITE_NB)), chunk_pad=32).compile()
+        kernels = ("mla_prefill_kernel", "moe_gmm_kernel")
+    text = c.as_text()
+    assert all(k in text for k in kernels)
+    # the expert matmul reads its layer's experts from the whole stack:
+    # no instruction makes a copy of one layer's (64, D, F) experts
+    E, D, F = LITE.num_experts, LITE.d_model, LITE.moe_d_ff
+    assert not re.search(rf"= bf16\[{E},({D},{F}|{F},{D})\]", text)
     m = c.memory_analysis()
     assert (m.argument_size_in_bytes + m.output_size_in_bytes
             + m.temp_size_in_bytes) < HBM_BYTES
