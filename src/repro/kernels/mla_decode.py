@@ -1,18 +1,19 @@
 """Paged flash-decode over MLA latent pages: every query head against
 one shared latent head.
 
-The pool is ``(num_pages, block_size, L)``: one latent of ``L = rank +
-rope`` values a token and layer (``models/mla.py``).  Scores run over
-all ``L`` lanes (absorbed query against ``[c, k_pe]``), values over the
-first ``rank`` lanes of the SAME page, so each page is read once.
+The pool is the model's stacked ``(num_layers, num_pages, block_size,
+L)``: one latent of ``L = rank + rope`` values a token and layer
+(``models/mla.py``).  Scores run over all ``L`` lanes (absorbed query
+against ``[c, k_pe]``), values over the first ``rank`` lanes of the
+SAME page, so each page is read once.
 
-Same walk as ``paged_decode_attention.py``: the block table and the
-lengths are scalar-prefetch operands, the pool stays in HBM
-(``memory_space=pl.ANY``) and the kernel DMAs each row's live pages by
-hand, ``P = min(128 // bs, nb)`` pages a chunk, into a two-slot VMEM
-buffer while the other slot is computed; the next row's first chunk is
-started at the end of a row.  A ``seq_len == 0`` row (dead slot) fetches
-nothing and returns zeros.
+Same walk as ``paged_decode_attention.py``: the block table, the
+lengths and the layer are scalar-prefetch operands, the pool stays in
+HBM (``memory_space=pl.ANY``) and the kernel DMAs the layer's live
+pages of each row by hand, ``P = min(128 // bs, nb)`` pages a chunk,
+into a two-slot VMEM buffer while the other slot is computed; the next
+row's first chunk is started at the end of a row.  A ``seq_len == 0``
+row (dead slot) fetches nothing and returns zeros.
 
   grid = (B,) ("arbitrary");
   per chunk: (H, L) x (P * bs, L) scores, masked by
@@ -32,13 +33,14 @@ from jax.experimental.pallas import tpu as pltpu
 NEG_INF = -1e30
 
 
-def _mla_fd_kernel(tables_ref, lens_ref, q_ref, pool_hbm, o_ref, buf, sem,
-                   chunk_ref, *, scale: float, block_size: int,
+def _mla_fd_kernel(tables_ref, lens_ref, layer_ref, q_ref, pool_hbm, o_ref,
+                   buf, sem, chunk_ref, *, scale: float, block_size: int,
                    pages_per_chunk: int, nb: int, rank: int):
     b = pl.program_id(0)
     nrows = pl.num_programs(0)
     bs, P = block_size, pages_per_chunk
     _, H, L = q_ref.shape
+    layer = layer_ref[0]
 
     def live_pages(row):
         return jnp.minimum(pl.cdiv(lens_ref[row], bs), nb)
@@ -52,7 +54,7 @@ def _mla_fd_kernel(tables_ref, lens_ref, q_ref, pool_hbm, o_ref, buf, sem,
             page_idx = c * P + j
             page = tables_ref[row * nb + jnp.minimum(page_idx, nb - 1)]
             out.append((page_idx < n, pltpu.make_async_copy(
-                pool_hbm.at[page], buf.at[slot, j], sem.at[slot])))
+                pool_hbm.at[layer, page], buf.at[slot, j], sem.at[slot])))
         return out
 
     def start(row, c, slot):
@@ -114,28 +116,30 @@ def _mla_fd_kernel(tables_ref, lens_ref, q_ref, pool_hbm, o_ref, buf, sem,
         start(b + 1, 0, next_chunk % 2)
 
 
-def mla_decode_attention(q, pool, block_tables, seq_lens, *, rank: int,
-                         interpret: bool = False):
+def mla_decode_attention(q, pool, block_tables, seq_lens, layer, *,
+                         rank: int, interpret: bool = False):
     """q: (B, H, L) absorbed queries (pre-scaled: the kernel divides by
-    sqrt(L), as the jnp path does); pool: (N, bs, L); block_tables:
+    sqrt(L), as the jnp path does); pool: stacked (num_layers, N, bs, L),
+    of which layer ``layer`` (i32 scalar, may be traced) is read — an
+    unstacked pool comes as ``pool[None]`` and layer 0; block_tables:
     (B, nb) i32; seq_lens: (B,) i32, clamped to ``nb * bs``.  Returns
     (B, H, rank): attention over the first ``rank`` lanes of each
     page."""
     B, H, L = q.shape
-    N, bs, _ = pool.shape
+    _, _, bs, _ = pool.shape
     _, nb = block_tables.shape
     P = max(1, min(128 // bs, nb))
     kernel = functools.partial(_mla_fd_kernel, scale=1.0 / (L ** 0.5),
                                block_size=bs, pages_per_chunk=P, nb=nb,
                                rank=rank)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,                   # block_tables, seq_lens
+        num_scalar_prefetch=3,           # block_tables, seq_lens, layer
         grid=(B,),
         in_specs=[
-            pl.BlockSpec((1, H, L), lambda b, t, s: (b, 0, 0)),
+            pl.BlockSpec((1, H, L), lambda b, t, s, l: (b, 0, 0)),
             pl.BlockSpec(memory_space=pl.ANY),   # the pool stays in HBM
         ],
-        out_specs=pl.BlockSpec((1, H, rank), lambda b, t, s: (b, 0, 0)),
+        out_specs=pl.BlockSpec((1, H, rank), lambda b, t, s, l: (b, 0, 0)),
         scratch_shapes=[
             pltpu.VMEM((2, P, bs, L), pool.dtype),
             pltpu.SemaphoreType.DMA((2,)),
@@ -151,4 +155,5 @@ def mla_decode_attention(q, pool, block_tables, seq_lens, *, rank: int,
         interpret=interpret,
         name="mla_decode_kernel",      # the device op's name in a trace
     )(block_tables.astype(jnp.int32).reshape(-1),
-      seq_lens.astype(jnp.int32), q, pool)
+      seq_lens.astype(jnp.int32), jnp.asarray(layer, jnp.int32).reshape(1),
+      q, pool)

@@ -90,9 +90,9 @@ def paged_decode_attention(q, k_pages, v_pages, block_tables, seq_lens, *,
         return _ref.paged_decode_attention_ref(q, k_pages, v_pages,
                                                block_tables, seq_lens)
     interp = _default_interpret() if interpret is None else interpret
-    return _pfd.paged_flash_decode_attention(q, k_pages, v_pages,
-                                             block_tables, seq_lens,
-                                             interpret=interp)
+    return _pfd.paged_flash_decode_attention(q, k_pages[None],
+                                             v_pages[None], block_tables,
+                                             seq_lens, 0, interpret=interp)
 
 
 @functools.partial(jax.jit, static_argnames=("use_pallas", "interpret"))

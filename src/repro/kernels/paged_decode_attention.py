@@ -7,10 +7,12 @@ through a ``(B, num_blocks)`` block table — KV memory scales with live
 tokens, not ``B * max_len`` (vLLM's PagedAttention, here as a Pallas
 TPU kernel).
 
-The pools stay in HBM in their own ``(N, bs, KV, D)`` layout
-(``memory_space=pl.ANY``) and the kernel fetches pages by hand: the
-block table and the lengths ride in as scalar-prefetch operands, so
-the page ids are known before any DMA is issued.
+The pools are the model's whole stacked pools ``(L, N, bs, KV, D)``,
+one ``(N, bs, KV, D)`` pool a layer; they stay in HBM in their own
+layout (``memory_space=pl.ANY``) and the kernel fetches layer
+``layer``'s pages by hand: the block table, the lengths and the layer
+ride in as scalar-prefetch operands, so the page ids are known before
+any DMA is issued, and no layer's pool is sliced out of the stack.
 
   grid = (B,) — one step a row, sequential ("arbitrary": a v5e has one
   TensorCore, so a parallel axis buys nothing);
@@ -36,8 +38,8 @@ from jax.experimental.pallas import tpu as pltpu
 NEG_INF = -1e30
 
 
-def _paged_fd_kernel(tables_ref, lens_ref, q_ref, k_hbm, v_hbm, o_ref,
-                     k_buf, v_buf, sem, chunk_ref, *, scale: float,
+def _paged_fd_kernel(tables_ref, lens_ref, layer_ref, q_ref, k_hbm, v_hbm,
+                     o_ref, k_buf, v_buf, sem, chunk_ref, *, scale: float,
                      block_size: int, pages_per_chunk: int, nb: int):
     b = pl.program_id(0)
     nrows = pl.num_programs(0)
@@ -45,6 +47,7 @@ def _paged_fd_kernel(tables_ref, lens_ref, q_ref, k_hbm, v_hbm, o_ref,
     KV = k_buf.shape[3]
     _, H, D = q_ref.shape
     G = H // KV
+    layer = layer_ref[0]
 
     def live_pages(row):
         return jnp.minimum(pl.cdiv(lens_ref[row], bs), nb)
@@ -63,9 +66,9 @@ def _paged_fd_kernel(tables_ref, lens_ref, q_ref, k_hbm, v_hbm, o_ref,
             # read whether or not the page is live: keep it in the row
             page = tables_ref[row * nb + jnp.minimum(page_idx, nb - 1)]
             out.append((live, pltpu.make_async_copy(
-                k_hbm.at[page], k_buf.at[slot, j], sem.at[0, slot])))
+                k_hbm.at[layer, page], k_buf.at[slot, j], sem.at[0, slot])))
             out.append((live, pltpu.make_async_copy(
-                v_hbm.at[page], v_buf.at[slot, j], sem.at[1, slot])))
+                v_hbm.at[layer, page], v_buf.at[slot, j], sem.at[1, slot])))
         return out
 
     def start(row, c, slot):
@@ -139,8 +142,10 @@ def _paged_fd_kernel(tables_ref, lens_ref, q_ref, k_hbm, v_hbm, o_ref,
 
 
 def paged_flash_decode_attention(q, k_pages, v_pages, block_tables,
-                                 seq_lens, *, interpret: bool = False):
-    """q: (B, H, D); pages: (N, bs, KV, D); block_tables: (B, nb) i32
+                                 seq_lens, layer, *, interpret: bool = False):
+    """q: (B, H, D); pages: stacked (L, N, bs, KV, D), of which layer
+    ``layer`` (i32 scalar, may be traced) is read — an unstacked pool
+    comes as ``pool[None]`` and layer 0; block_tables: (B, nb) i32
     physical page ids (pad with any valid id, e.g. 0); seq_lens: (B,)
     i32 valid logical lengths, clamped to ``nb * bs``.  Returns
     (B, H, D).
@@ -151,7 +156,7 @@ def paged_flash_decode_attention(q, k_pages, v_pages, block_tables,
     so only rows with ``seq_len >= 1`` are comparable against it).
     """
     B, H, D = q.shape
-    N, bs, KV, _ = k_pages.shape
+    _, _, bs, KV, _ = k_pages.shape
     _, nb = block_tables.shape
     G = H // KV
     P = max(1, min(128 // bs, nb))
@@ -159,14 +164,14 @@ def paged_flash_decode_attention(q, k_pages, v_pages, block_tables,
     kernel = functools.partial(_paged_fd_kernel, scale=1.0 / (D ** 0.5),
                                block_size=bs, pages_per_chunk=P, nb=nb)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,                   # block_tables, seq_lens
+        num_scalar_prefetch=3,           # block_tables, seq_lens, layer
         grid=(B,),
         in_specs=[
-            pl.BlockSpec((1, H, D), lambda b, t, s: (b, 0, 0)),
+            pl.BlockSpec((1, H, D), lambda b, t, s, l: (b, 0, 0)),
             pl.BlockSpec(memory_space=pl.ANY),   # pools stay in HBM
             pl.BlockSpec(memory_space=pl.ANY),
         ],
-        out_specs=pl.BlockSpec((1, H, D), lambda b, t, s: (b, 0, 0)),
+        out_specs=pl.BlockSpec((1, H, D), lambda b, t, s, l: (b, 0, 0)),
         scratch_shapes=[
             pltpu.VMEM((2, P, bs, KV, D), k_pages.dtype),
             pltpu.VMEM((2, P, bs, KV, D), v_pages.dtype),
@@ -183,5 +188,6 @@ def paged_flash_decode_attention(q, k_pages, v_pages, block_tables,
         interpret=interpret,
         name="paged_decode_kernel",    # the device op's name in a trace
     )(block_tables.astype(jnp.int32).reshape(-1),
-      seq_lens.astype(jnp.int32), q, k_pages, v_pages)
+      seq_lens.astype(jnp.int32), jnp.asarray(layer, jnp.int32).reshape(1),
+      q, k_pages, v_pages)
     return out

@@ -41,14 +41,28 @@ def _flat(pages: Array) -> Array:
     return pages.reshape((N * bs,) + pages.shape[2:])
 
 
-def gather_tokens(pages: Array, tables: Array) -> Array:
+def _layer_view(pages: Array, tables: Array, layer) -> tuple:
+    """A stacked pool ``(L, N, bs, *feat)`` read at ``layer`` (traced
+    scalar): every layer's pages as one ``(L*N, bs, *feat)`` pool (a
+    reshape of leading dims, no copy) and the tables shifted to that
+    layer's pages.  ``layer=None``: an unstacked pool, as it is."""
+    if layer is None:
+        return pages, tables
+    L, N = pages.shape[:2]
+    return (pages.reshape((L * N,) + pages.shape[2:]),
+            tables + jnp.asarray(layer, tables.dtype) * N)
+
+
+def gather_tokens(pages: Array, tables: Array, layer=None) -> Array:
     """Gather each sequence's tokens in logical order.
 
-    pages: (N, bs, *feat); tables: (B, nb) i32 physical block ids.
+    pages: (N, bs, *feat), or a stacked (L, N, bs, *feat) read at
+    ``layer``; tables: (B, nb) i32 physical block ids.
     Returns (B, nb*bs, *feat) — row b's logical positions 0..nb*bs-1.
     Entries past a sequence's written length are whatever the page
     holds (zeros or stale data); callers mask by valid length.
     """
+    pages, tables = _layer_view(pages, tables, layer)
     bs = pages.shape[1]
     B, nb = tables.shape
     idx = (tables[:, :, None] * bs
@@ -57,22 +71,25 @@ def gather_tokens(pages: Array, tables: Array) -> Array:
 
 
 def scatter_token(pages: Array, values: Array, tables: Array,
-                  pos: Array) -> Array:
+                  pos: Array, layer=None) -> Array:
     """Write one token per sequence at its current logical position.
 
-    pages: (N, bs, *feat); values: (B, *feat); tables: (B, nb) i32;
-    pos: (B,) i32 logical positions.  Distinct sequences own distinct
-    blocks (allocator invariant), so rows never collide.  The table
-    lookup clamps ``pos // bs`` to the table width: evicted (dead) decode
-    rows keep stepping with a stale, ever-growing ``pos``, and their
-    table rows point at the reserved trash page — the clamp makes every
-    dead-row write land there instead of indexing out of bounds.
+    pages: (N, bs, *feat), or a stacked (L, N, bs, *feat) written at
+    ``layer`` (its other layers untouched); values: (B, *feat); tables:
+    (B, nb) i32; pos: (B,) i32 logical positions.  Distinct sequences
+    own distinct blocks (allocator invariant), so rows never collide.
+    The table lookup clamps ``pos // bs`` to the table width: evicted
+    (dead) decode rows keep stepping with a stale, ever-growing ``pos``,
+    and their table rows point at the reserved trash page — the clamp
+    makes every dead-row write land there instead of indexing out of
+    bounds.
     """
-    bs = pages.shape[1]
+    flat, tables = _layer_view(pages, tables, layer)
+    bs = flat.shape[1]
     blk_idx = jnp.minimum(pos[:, None] // bs, tables.shape[1] - 1)
     blk = jnp.take_along_axis(tables, blk_idx, axis=1)[:, 0]
     flat_idx = blk * bs + pos % bs
-    out = _flat(pages).at[flat_idx].set(values.astype(pages.dtype))
+    out = _flat(flat).at[flat_idx].set(values.astype(pages.dtype))
     return out.reshape(pages.shape)
 
 

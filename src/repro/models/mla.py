@@ -129,29 +129,34 @@ def attend_ring(p: dict, q: Array, cache: Array, pos, slot_pos, cfg):
 
 
 def decode_paged(p: dict, h: Array, pool: Array, pos: Array, tables: Array,
-                 cfg, use_pallas: bool = False):
-    """One token a row against the latent page pool (N, bs, L).
+                 cfg, use_pallas: bool = False, layer=None):
+    """One token a row against the latent page pool (N, bs, L), or the
+    scanned stack's whole (num_layers, N, bs, L) pool of which this is
+    layer ``layer`` (traced), written and read there in place.
     h: (B, 1, D) normed; pos (B,); tables (B, nb).  The new latent is
     written first.  Returns (out (B, 1, D), new pool)."""
     R = cfg.kv_lora_rank
+    stacked = layer is not None
+    if not stacked:
+        pool, layer = pool[None], 0
     q, lat = project(p, h, pos[:, None], cfg)
-    pool = paged_lib.scatter_token(pool, lat[:, 0], tables, pos)
+    pool = paged_lib.scatter_token(pool, lat[:, 0], tables, pos, layer)
     if use_pallas:
         # a table that starts at the trash page (the pool's last) is a
         # dead row: the kernel walks none of its pages
-        dead = tables[:, 0] == pool.shape[0] - 1
+        dead = tables[:, 0] == pool.shape[1] - 1
         o = mla_decode_kernel.mla_decode_attention(
-            q[:, 0], pool, tables, jnp.where(dead, 0, pos + 1), rank=R,
-            interpret=kernel_ops._default_interpret())[:, None]
+            q[:, 0], pool, tables, jnp.where(dead, 0, pos + 1), layer,
+            rank=R, interpret=kernel_ops._default_interpret())[:, None]
     else:
-        seq = paged_lib.gather_tokens(pool, tables)      # (B, nb*bs, L)
+        seq = paged_lib.gather_tokens(pool, tables, layer)  # (B, nb*bs, L)
         n = seq.shape[1]
         kv_pos = jnp.broadcast_to(jnp.arange(n, dtype=jnp.int32),
                                   (h.shape[0], n))
         o = layers.decode_attention(
             q, seq[:, :, None], _values(seq, R)[:, :, None],
             q_position=pos, kv_positions=kv_pos, valid_len=pos + 1)[..., :R]
-    return attn_out(p, o), pool
+    return attn_out(p, o), pool if stacked else pool[0]
 
 
 def chunks_paged(p: dict, h: Array, pool: Array, ctx: dict, cfg):

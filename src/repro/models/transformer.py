@@ -200,15 +200,18 @@ def _attn_decode(p, x, cache_k, cache_v, pos, slot_pos, cfg, window):
 
 
 def _attn_decode_paged(p, x, pages_k, pages_v, pos, tables, cfg,
-                       use_pallas: bool = False):
+                       use_pallas: bool = False, layer=None):
     """One-token self attention against a paged (block-table) KV cache.
 
     pos: (B,) per-slot logical positions; tables: (B, nb) i32 physical
-    page ids; pages_k/v: (N, bs, KV, D).  The new token scatters into
-    page ``tables[s, pos[s]//bs]`` and attention runs over the paged
-    pool — positions 0..pos are bit-identical to the contiguous slot
-    cache's layout (absolute-position order, masked tail), so the paged
-    engine matches the contiguous engine token for token.
+    page ids; pages_k/v: (N, bs, KV, D), or the scanned stack's whole
+    (L, N, bs, KV, D) pools of which this is layer ``layer`` (traced;
+    ``apply_stack``), written and read there, so no layer's pool is
+    sliced out.  The new token scatters into page
+    ``tables[s, pos[s]//bs]`` and attention runs over the paged pool —
+    positions 0..pos are bit-identical to the contiguous slot cache's
+    layout (absolute-position order, masked tail), so the paged engine
+    matches the contiguous engine token for token.
 
     ``use_pallas`` routes the attention through the Pallas
     ``paged_decode_attention`` kernel, which DMAs each row's live pages
@@ -222,26 +225,32 @@ def _attn_decode_paged(p, x, pages_k, pages_v, pos, tables, cfg,
     h = rms_norm(x, p["ln1"], cfg.norm_eps)
     q, k, v = layers.attention_qkv(p["attn"], h, pos[..., None],
                                    cfg.rope_theta)
-    new_k = paged_lib.scatter_token(pages_k, k[:, 0], tables, pos)
-    new_v = paged_lib.scatter_token(pages_v, v[:, 0], tables, pos)
+    stacked = layer is not None
+    if not stacked:
+        pages_k, pages_v, layer = pages_k[None], pages_v[None], 0
+    new_k = paged_lib.scatter_token(pages_k, k[:, 0], tables, pos, layer)
+    new_v = paged_lib.scatter_token(pages_v, v[:, 0], tables, pos, layer)
     if use_pallas:
         # rows whose table starts at the trash page (the pool's last
         # page, ``PagedKVCache.trash_block == num_blocks``) are evicted
         # slots or slots still in prefill: the engine discards their
         # outputs, so the kernel walks none of their pages
-        dead = tables[:, 0] == pages_k.shape[0] - 1
+        dead = tables[:, 0] == pages_k.shape[1] - 1
         attn = pfd_kernel.paged_flash_decode_attention(
             q[:, 0], new_k, new_v, tables, jnp.where(dead, 0, pos + 1),
-            interpret=kernel_ops._default_interpret())[:, None]
+            layer, interpret=kernel_ops._default_interpret())[:, None]
     else:
-        k_seq = paged_lib.gather_tokens(new_k, tables)  # (B, nb*bs, KV, D)
-        v_seq = paged_lib.gather_tokens(new_v, tables)
+        # (B, nb*bs, KV, D)
+        k_seq = paged_lib.gather_tokens(new_k, tables, layer)
+        v_seq = paged_lib.gather_tokens(new_v, tables, layer)
         L = k_seq.shape[1]
         kv_pos = jnp.broadcast_to(jnp.arange(L, dtype=jnp.int32),
                                   (x.shape[0], L))
         attn = layers.decode_attention(
             q, k_seq, v_seq, q_position=pos, kv_positions=kv_pos,
             valid_len=pos + 1, window=None)
+    if not stacked:
+        new_k, new_v = new_k[0], new_v[0]
     return x + layers.attention_out(p["attn"], attn), new_k, new_v
 
 
@@ -524,9 +533,11 @@ def apply_block_decode(kind, p, x, ctx, cfg, cache):
         valid = None
         if tables is not None:
             y, ring = mla.decode_paged(p["attn"], h, cache["ckv"], pos,
-                                       tables, cfg, use_pallas)
-            # rows at the trash page are dead slots: routed nowhere
-            valid = (tables[:, 0] != cache["ckv"].shape[0] - 1)[:, None]
+                                       tables, cfg, use_pallas,
+                                       ctx.get("layer"))
+            # rows at the trash page (the pool's last; the pool may
+            # carry a leading layer axis) are dead slots: routed nowhere
+            valid = (tables[:, 0] != cache["ckv"].shape[-3] - 1)[:, None]
         else:
             q, lat = mla.project(p["attn"], h, pos[..., None], cfg)
             ring, _ = decode_write_kv(cache["ckv"], cache["ckv"], lat, lat,
@@ -545,8 +556,8 @@ def apply_block_decode(kind, p, x, ctx, cfg, cache):
     if kind in ("dense", "moe", "cross"):
         if tables is not None:
             x, nk, nv = _attn_decode_paged(p, x, cache["k"], cache["v"],
-                                           pos, tables, cfg,
-                                           ctx.get("use_pallas", False))
+                                           pos, tables, cfg, use_pallas,
+                                           ctx.get("layer"))
         else:
             x, nk, nv, _ = _attn_decode(p, x, cache["k"], cache["v"], pos,
                                         ctx["slot_pos"], cfg, cfg.window)
@@ -655,11 +666,20 @@ def apply_stack(params: dict, x: Array, ctx: dict, cfg, cache=None,
 
     if n > 0:
         p_stacked = tuple(params[f"scan{s}"] for s in range(len(pat)))
-        # The serving expert layer reads its experts' weights from the
-        # whole stack by layer index: a kernel operand sliced out of the
-        # stack inside the scan would be copied whole every layer.
+        c_stacked = (None if cache is None
+                     else tuple(cache[f"scan{s}"] for s in range(len(pat))))
+        # A serve on one device hands each layer operands that stay in
+        # the whole scanned stack, with the layer's index: a kernel
+        # operand sliced out of the stack inside the scan would be
+        # copied whole every layer.  The expert layer reads its
+        # experts' weights so; the paged decode step carries the KV
+        # pools through the scan whole and writes only the new token's
+        # rows (``_attn_decode_paged``, ``mla.decode_paged``).
+        in_place = cache is not None and shctx.current() is None
+        carry_pools = (in_place and mode == "decode"
+                       and ctx.get("tables") is not None)
         whole = [{} for _ in pat]
-        if cache is not None and shctx.current() is None:
+        if in_place:
             for s, kind in enumerate(pat):
                 if kind == "moe":
                     m = p_stacked[s]["moe"]
@@ -668,8 +688,10 @@ def apply_stack(params: dict, x: Array, ctx: dict, cfg, cache=None,
                         k: v for k, v in m.items()
                         if k not in EXPERT_WEIGHTS}),) + p_stacked[s + 1:]
 
-        def superblock(x, inp):
-            ps, cs, i = inp if any(whole) else inp + (None,)
+        def superblock(carry, inp):
+            x, pools = carry
+            ps, cs, i = inp
+            cs = pools if pools is not None else cs
             auxes = dict(ZERO_AUX)
             ncs = [None] * len(pat)
             for s, kind in enumerate(pat):
@@ -677,26 +699,26 @@ def apply_stack(params: dict, x: Array, ctx: dict, cfg, cache=None,
                 p, cx = ps[s], ctx
                 if whole[s]:
                     p = dict(p, moe=dict(p["moe"], **whole[s]))
+                if i is not None:
                     cx = dict(ctx, layer=i)
                 x, nc, a = apply_fn(kind, p, x, cx, cfg, c)
                 auxes = _sum_aux(auxes, a)
                 ncs[s] = nc
-            if cs is None:
-                return x, auxes
-            return x, (tuple(ncs), auxes)
+            ncs = None if cs is None else tuple(ncs)
+            if pools is not None:
+                return (x, ncs), (None, auxes)
+            return (x, None), (ncs, auxes)
 
         body = jax.checkpoint(superblock) if (remat and mode == "train") \
             else superblock
-        layer_idx = ((jnp.arange(n, dtype=jnp.int32),) if any(whole)
-                     else ())
-        if cache is None:
-            x, auxes = lax.scan(body, x, (p_stacked, None) + layer_idx)
-        else:
-            c_stacked = tuple(cache[f"scan{s}"] for s in range(len(pat)))
-            x, (nc_stacked, auxes) = lax.scan(
-                body, x, (p_stacked, c_stacked) + layer_idx)
-            for s in range(len(pat)):
-                new_cache[f"scan{s}"] = nc_stacked[s]
+        layer_idx = jnp.arange(n, dtype=jnp.int32) if in_place else None
+        pools = c_stacked if carry_pools else None
+        (x, pools), (nc_stacked, auxes) = lax.scan(
+            body, (x, pools),
+            (p_stacked, None if carry_pools else c_stacked, layer_idx))
+        if cache is not None:
+            for s, nc in enumerate(pools if carry_pools else nc_stacked):
+                new_cache[f"scan{s}"] = nc
         aux = _sum_aux(aux, jax.tree.map(jnp.sum, auxes))
 
     for i, kind in enumerate(tail):

@@ -250,11 +250,16 @@ def make_paged_decode_steps_fn(cfg, use_pallas: Optional[bool] = None):
     ``num_steps`` (static) scan iterations against the page pool in ONE
     launch.  Block tables are fixed across the window — the engine
     pre-extends them to ``kvcache.window_target_tokens`` — so the scan
-    needs no host round-trip."""
+    needs no host round-trip.
+
+    ``cache`` is donated: the launch writes the window's tokens into the
+    page pools in place, and the caller must not read the cache it
+    passed in, only the one returned."""
     use_pallas = resolve_use_pallas(use_pallas)
 
     def build():
-        @functools.partial(jax.jit, static_argnames=("num_steps",))
+        @functools.partial(jax.jit, static_argnames=("num_steps",),
+                           donate_argnames=("cache",))
         def paged_decode_steps_fn(params, cache, token, tables, *,
                                   num_steps):
             return model_lib.decode_steps_paged(

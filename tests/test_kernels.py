@@ -86,29 +86,46 @@ def _paged_case(B, N, nb, block_size, layout, rng):
     return jnp.asarray(tables), jnp.asarray(lens.astype(np.int32))
 
 
+def _stack_at(pages, layer, depth=3):
+    """``pages`` as layer ``layer`` of a ``depth``-layer stack whose
+    other layers hold NaN: a read of the wrong layer poisons the
+    output."""
+    stack = jnp.full((depth,) + pages.shape, jnp.nan, pages.dtype)
+    return stack.at[layer].set(pages)
+
+
 @pytest.mark.parametrize("dtype", DTYPES)
-@pytest.mark.parametrize("B,H,KV,D,block_size,nb,layout", [
-    pytest.param(2, 4, 2, 32, 16, 4, "random",
+@pytest.mark.parametrize("B,H,KV,D,block_size,nb,layout,layer", [
+    pytest.param(2, 4, 2, 32, 16, 4, "random", None,
                  id="2-4-2-32-16-4"),        # GQA, 4-entry tables
-    pytest.param(1, 8, 1, 64, 32, 3, "random",
+    pytest.param(1, 8, 1, 64, 32, 3, "random", None,
                  id="1-8-1-64-32-3"),        # MQA
-    pytest.param(3, 4, 4, 16, 64, 2, "random",
+    pytest.param(3, 4, 4, 16, 64, 2, "random", None,
                  id="3-4-4-16-64-2"),        # MHA, big pages
-    pytest.param(2, 8, 2, 128, 16, 5, "random",
+    pytest.param(2, 8, 2, 128, 16, 5, "random", None,
                  id="2-8-2-128-16-5"),       # long table, wide heads
     # StarCoder2-3B's heads and the cell's 24-entry tables
-    pytest.param(3, 24, 2, 128, 16, 24, "random", id="sc2-heads"),
-    pytest.param(2, 4, 2, 32, 16, 11, "random",
+    pytest.param(3, 24, 2, 128, 16, 24, "random", None, id="sc2-heads"),
+    pytest.param(2, 4, 2, 32, 16, 11, "random", None,
                  id="nb-not-multiple-of-P"),  # chunks of 8 and 3
-    pytest.param(3, 8, 2, 64, 16, 10, "boundary", id="page-boundaries"),
-    pytest.param(2, 8, 2, 64, 16, 11, "clamped", id="len-past-table"),
-    pytest.param(2, 8, 2, 64, 16, 6, "shared", id="shared-prefix-page"),
-    pytest.param(2, 8, 2, 64, 16, 6, "empty", id="dead-row"),
+    pytest.param(3, 8, 2, 64, 16, 10, "boundary", None,
+                 id="page-boundaries"),
+    pytest.param(2, 8, 2, 64, 16, 11, "clamped", None, id="len-past-table"),
+    pytest.param(2, 8, 2, 64, 16, 6, "shared", None, id="shared-prefix-page"),
+    pytest.param(2, 8, 2, 64, 16, 6, "empty", None, id="dead-row"),
+    # layer 1 of a 3-layer stack, the others NaN (the decode scan's pools)
+    pytest.param(3, 24, 2, 128, 16, 24, "random", 1, id="sc2-heads-layer1"),
+    pytest.param(3, 8, 2, 64, 16, 10, "boundary", 1,
+                 id="page-boundaries-layer1"),
+    pytest.param(2, 8, 2, 64, 16, 6, "empty", 1, id="dead-row-layer1"),
 ])
-def test_paged_decode_sweep(B, H, KV, D, block_size, nb, layout, dtype):
+def test_paged_decode_sweep(B, H, KV, D, block_size, nb, layout, layer,
+                            dtype):
     """Paged flash-decode vs the block-table gather oracle across block
     sizes and RAGGED per-sequence lengths (tables deliberately permuted
-    so physical order != logical order)."""
+    so physical order != logical order).  ``layer``: the pools are that
+    layer of a stack whose other layers hold NaN; ``None``: an
+    unstacked pool, passed as a one-layer stack at layer 0."""
     N = B * nb + 3               # spare pages: stale/garbage content
     ks = jax.random.split(jax.random.PRNGKey(4), 3)
     q = jax.random.normal(ks[0], (B, H, D), jnp.float32).astype(dtype)
@@ -118,8 +135,12 @@ def test_paged_decode_sweep(B, H, KV, D, block_size, nb, layout, dtype):
                            jnp.float32).astype(dtype)
     rng = np.random.default_rng(B * 131 + block_size)
     tables, lens = _paged_case(B, N, nb, block_size, layout, rng)
-    out = pfd.paged_flash_decode_attention(q, kp, vp, tables, lens,
-                                           interpret=True)
+    if layer is None:
+        kstack, vstack, at = kp[None], vp[None], 0
+    else:
+        kstack, vstack, at = _stack_at(kp, layer), _stack_at(vp, layer), layer
+    out = pfd.paged_flash_decode_attention(q, kstack, vstack, tables, lens,
+                                           jnp.int32(at), interpret=True)
     assert out.shape == (B, H, D) and out.dtype == dtype
     live = np.asarray(lens) > 0
     # the oracle is NaN on a length-0 row; the kernel returns zeros
@@ -152,7 +173,8 @@ def test_paged_decode_matches_contiguous_decode():
     got = ref.paged_decode_attention_ref(q, kp, vp, tables, lens)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                atol=2e-5, rtol=2e-5)
-    got_kernel = pfd.paged_flash_decode_attention(q, kp, vp, tables, lens,
+    got_kernel = pfd.paged_flash_decode_attention(q, kp[None], vp[None],
+                                                  tables, lens, 0,
                                                   interpret=True)
     np.testing.assert_allclose(np.asarray(got_kernel), np.asarray(want),
                                atol=2e-5, rtol=2e-5)
@@ -169,8 +191,8 @@ def test_paged_decode_empty_row_returns_zeros():
     vp = jax.random.normal(ks[2], (N, bs, KV, D))
     tables = jnp.arange(N, dtype=jnp.int32).reshape(B, nb)
     lens = jnp.asarray([0, 11], jnp.int32)
-    out = pfd.paged_flash_decode_attention(q, kp, vp, tables, lens,
-                                           interpret=True)
+    out = pfd.paged_flash_decode_attention(q, kp[None], vp[None], tables,
+                                           lens, 0, interpret=True)
     np.testing.assert_array_equal(np.asarray(out[0]),
                                   np.zeros((H, D), np.float32))
     want = ref.paged_decode_attention_ref(q, kp, vp, tables, lens)

@@ -108,3 +108,27 @@ def test_roundtrip_with_permuted_table_and_stale_pages():
     got = gather_tokens(pages, table[None, :])[0, :S]
     np.testing.assert_allclose(np.asarray(got), np.asarray(seq),
                                atol=0, rtol=0)
+
+
+def test_scatter_token_writes_one_layer_of_a_stack():
+    """The decode scan writes a token into layer ``layer`` of the whole
+    stacked pool: that layer's pages take the rows (a dead row, its
+    table at the trash page and its position past the table, lands in
+    the trash page), every other layer is untouched, and the gather at
+    that layer reads the sequence back."""
+    bs, nb, N, depth, layer = 4, 3, 8, 3, 1
+    trash = N - 1
+    rng = np.random.default_rng(2)
+    stack = jnp.asarray(rng.normal(size=(depth, N, bs, 2)).astype(np.float32))
+    tables = jnp.asarray([[5, 1, 6], [trash] * nb], jnp.int32)
+    pos = jnp.asarray([5, 40], jnp.int32)          # row 1: dead, stale pos
+    vals = jnp.asarray(rng.normal(size=(2, 2)).astype(np.float32))
+    out = scatter_token(stack, vals, tables, pos, jnp.int32(layer))
+    want = stack.at[layer, 1, 1].set(vals[0]).at[layer, trash, 0].set(vals[1])
+    np.testing.assert_array_equal(np.asarray(out), np.asarray(want))
+    # the same write into the unstacked layer, and the gather at the layer
+    one = scatter_token(stack[layer], vals, tables, pos)
+    np.testing.assert_array_equal(np.asarray(out[layer]), np.asarray(one))
+    np.testing.assert_array_equal(
+        np.asarray(gather_tokens(out, tables, jnp.int32(layer))),
+        np.asarray(gather_tokens(one, tables)))

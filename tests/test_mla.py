@@ -197,7 +197,11 @@ def test_grouped_matmul_kernel_reads_one_layer_of_a_stack():
                                    np.asarray(want, np.float32), atol=0.25)
 
 
-def test_latent_decode_kernel_equals_the_jnp_path():
+def _latent_decode_case(layer):
+    """The latent decode kernel against the jnp path; ``layer``: the
+    pool is that layer of a 3-layer stack whose other layers hold NaN,
+    so a read of the wrong layer fails; ``None``: an unstacked pool,
+    passed as a one-layer stack at layer 0."""
     B, H, L, R, bs, nb = 3, 4, 128, 64, 16, 4
     q = jax.random.normal(jax.random.PRNGKey(7),
                           (B, H, L)).astype(jnp.bfloat16)
@@ -205,7 +209,13 @@ def test_latent_decode_kernel_equals_the_jnp_path():
                              (12, bs, L)).astype(jnp.bfloat16)
     tables = jnp.asarray([[3, 5, 7, 1], [2, 0, 11, 11], [11] * 4], jnp.int32)
     lens = jnp.asarray([50, 20, 0], jnp.int32)
-    got = mla_decode.mla_decode_attention(q, pool, tables, lens, rank=R,
+    if layer is None:
+        stack, layer = pool[None], 0
+    else:
+        stack = jnp.full((3,) + pool.shape, jnp.nan,
+                         pool.dtype).at[layer].set(pool)
+    got = mla_decode.mla_decode_attention(q, stack, tables, lens,
+                                          jnp.int32(layer), rank=R,
                                           interpret=True)
     seq = paged_lib.gather_tokens(pool, tables)
     want = layers.decode_attention(
@@ -215,6 +225,16 @@ def test_latent_decode_kernel_equals_the_jnp_path():
     np.testing.assert_allclose(np.asarray(got[:2], np.float32),
                                np.asarray(want[:2], np.float32), atol=2e-2)
     assert float(jnp.abs(got[2].astype(jnp.float32)).max()) == 0.0
+
+
+def test_latent_decode_kernel_equals_the_jnp_path():
+    _latent_decode_case(None)
+
+
+def test_latent_decode_kernel_reads_one_layer_of_a_stack():
+    """The decode scan hands the kernel the whole stacked latent pool
+    and the layer's index: it reads that layer's pages alone."""
+    _latent_decode_case(1)
 
 
 def test_latent_prefill_kernel_equals_the_jnp_path():

@@ -29,6 +29,7 @@ from repro.core import scheduler as sched, simulator
 from repro.kvcache import paged as paged_lib
 from repro.models import model as model_lib, transformer
 from repro.obs import Observability
+from repro.serving import generate
 from repro.serving.engine import Request, ServingEngine
 
 SLOTS = 3
@@ -227,6 +228,34 @@ def test_dead_row_gives_kernel_length_zero(setup):
     np.testing.assert_allclose(np.asarray(out["live"], np.float32),
                                np.asarray(ref_out, np.float32),
                                atol=5e-2, rtol=5e-2)
+
+
+def test_persistent_pool_survives_the_donated_decode_window(setup):
+    """The decode window donates the cache and updates the stacked pools
+    in place: the cache passed in is gone after the launch.  Two serves
+    on one engine with a persistent prefix cache, the second hitting
+    prefixes the first wrote into the pool, read no donated buffer and
+    serve the tokens a cold engine serves."""
+    cfg, params, _, _, test = setup
+    window = generate.make_paged_decode_steps_fn(cfg, False)
+    cache = transformer.init_paged_cache(cfg, SLOTS, 8, 4)
+    tables = jnp.full((SLOTS, 2), 7, jnp.int32)
+    window(params, cache, jnp.zeros((SLOTS, 1), jnp.int32), tables,
+           num_steps=1)
+    assert all(a.is_deleted() for a in jax.tree.leaves(cache))
+
+    kw = dict(kv="paged", kv_block_size=4, prefix_cache=True,
+              prefill="chunked", chunk_size=4, token_budget=8)
+    eng = _engine(setup, persist_prefix_cache=True, **kw)
+    first = eng.serve(_requests(test, CAPS))
+    second = eng.serve(_requests(test, CAPS))
+    cold = _engine(setup, **kw).serve(_requests(test, CAPS))
+    assert second["prefix_hit_rate"] > first["prefix_hit_rate"]
+
+    def tokens(res):
+        return {t.task.task_id: t.task.out_tokens for t in res["tasks"]}
+
+    assert tokens(second) == tokens(first) == tokens(cold)
 
 
 @pytest.mark.parametrize("prefill", ["stall", "chunked"])

@@ -83,38 +83,66 @@ bf16, i32 = jnp.bfloat16, jnp.int32
 
 def _pool_relayouts(compiled) -> list:
     """Copies and transposes in the compiled program that produce an
-    array of one KV pool's size: the pool read in another layout than
-    its own ``(N, bs, KV, D)``."""
+    array of one KV pool's size, or the stacked pools': a pool read in
+    another layout than its own ``(N, bs, KV, D)``."""
     n = NUM_PAGES * BS * KV * D
     out = []
     for line in compiled.as_text().splitlines():
         m = re.search(r"= bf16\[([\d,]*)\]\S* (copy|transpose)\(", line)
-        if m and math.prod(int(d) for d in m.group(1).split(",")) == n:
+        if m and math.prod(int(d) for d in m.group(1).split(",")) in (
+                n, CFG.num_layers * n):
             out.append(line.strip()[:160])
     return out
+
+
+def _pool_copies(compiled, one_layer: tuple, stacked: str) -> list:
+    """Instructions of the compiled program that make one layer's pool
+    (shapes ``one_layer``; a bitcast or a parameter makes none) or
+    write the ``stacked`` pools by a dynamic-update-slice: the pool of
+    a scanned layer sliced out of the stack and written back."""
+    out = []
+    for line in compiled.as_text().splitlines():
+        m = re.search(r"= (bf16\[[\d,]*\])\S* ([\w-]+)\(", line)
+        if not m:
+            continue
+        shape, op = m.groups()
+        if ((shape in one_layer and op not in ("bitcast", "parameter"))
+                or (shape == stacked and op == "dynamic-update-slice")):
+            out.append(line.strip()[:160])
+    return out
+
+
+def _pool_bytes(cache) -> int:
+    return sum(a.size * a.dtype.itemsize for key, layer in cache.items()
+               if key.startswith(("prefix", "scan"))
+               for a in jax.tree.leaves(layer))
 
 
 def test_paged_decode_attention_compiles(one_chip):
     B = 16
     c = _compile(functools.partial(pfd.paged_flash_decode_attention,
                                    interpret=False), one_chip,
-                 ((B, H, D), bf16), ((NUM_PAGES, BS, KV, D), bf16),
-                 ((NUM_PAGES, BS, KV, D), bf16), ((B, NB), i32), ((B,), i32))
+                 ((B, H, D), bf16), ((1, NUM_PAGES, BS, KV, D), bf16),
+                 ((1, NUM_PAGES, BS, KV, D), bf16), ((B, NB), i32),
+                 ((B,), i32), ((), i32))
     assert _has_kernel(c)
 
 
 def test_paged_decode_reads_pool_in_place(one_chip):
-    """At sc2-chat-steady's shapes (64 slots, 24-entry tables) the
-    kernel DMAs pages from the pools as they are: no transposed
-    ``(KV, N, bs, D)`` copy of a pool feeds it."""
-    B, nb = 64, 24
+    """At sc2-chat-steady's shapes (64 slots, 24-entry tables, the 30
+    layers' stacked pools) the kernel DMAs one layer's pages from the
+    stacked pools as they are: no transposed ``(KV, N, bs, D)`` copy and
+    no one-layer slice of a pool feeds it."""
+    B, nb, L = 64, 24, CFG.num_layers
     c = _compile(functools.partial(pfd.paged_flash_decode_attention,
                                    interpret=False), one_chip,
-                 ((B, H, D), bf16), ((NUM_PAGES, BS, KV, D), bf16),
-                 ((NUM_PAGES, BS, KV, D), bf16), ((B, nb), i32), ((B,), i32))
+                 ((B, H, D), bf16), ((L, NUM_PAGES, BS, KV, D), bf16),
+                 ((L, NUM_PAGES, BS, KV, D), bf16), ((B, nb), i32),
+                 ((B,), i32), ((), i32))
     assert _has_kernel(c)
     assert f"bf16[{KV},{NUM_PAGES},{BS},{D}]" not in c.as_text()
     assert _pool_relayouts(c) == []
+    assert _pool_copies(c, (f"bf16[{NUM_PAGES},{BS},{KV},{D}]",), "") == []
 
 
 def test_chunked_prefill_attention_compiles(one_chip):
@@ -152,8 +180,11 @@ def test_rms_norm_compiles(one_chip):
 def test_full_width_step_compiles_and_fits(one_chip, monkeypatch, step):
     """The engine's whole step programs at published widths: the model's
     kernel sites must pick the compiled kernel (``_default_interpret``
-    steered here, since the backend is the CPU), and parameters plus the
-    KV pool held twice (no donation) must fit one chip."""
+    steered here, since the backend is the CPU), and parameters, the KV
+    pool and the output, counted apart, must fit one chip.  The decode
+    window updates the stacked pools in place: no scanned layer's pool
+    is sliced out and written back, and the donated pools are aliased
+    from input to output."""
     monkeypatch.setattr(ops, "_default_interpret", lambda: False)
 
     def sds(tree):
@@ -177,9 +208,14 @@ def test_full_width_step_compiles_and_fits(one_chip, monkeypatch, step):
         c = fn.lower(params, cache, {"tokens": arg((1, 512))}, arg((512,)),
                      arg((1, 4)), arg((1, NB)), chunk_pad=512).compile()
     assert _has_kernel(c)
+    m = c.memory_analysis()
     if step == "decode_window":
         assert _pool_relayouts(c) == []
-    m = c.memory_analysis()
+        one = (f"bf16[1,{NUM_PAGES},{BS},{KV},{D}]",
+               f"bf16[{NUM_PAGES},{BS},{KV},{D}]")
+        stacked = f"bf16[{CFG.num_layers},{NUM_PAGES},{BS},{KV},{D}]"
+        assert _pool_copies(c, one, stacked) == []
+        assert m.alias_size_in_bytes >= _pool_bytes(cache)
     assert (m.argument_size_in_bytes + m.output_size_in_bytes
             + m.temp_size_in_bytes) < HBM_BYTES
 
@@ -195,8 +231,8 @@ def test_mla_decode_attention_compiles(one_chip):
     c = _compile(functools.partial(mla_decode.mla_decode_attention,
                                    rank=LITE.kv_lora_rank, interpret=False),
                  one_chip, ((ROWS, LITE.num_heads, LANES), bf16),
-                 ((NUM_PAGES, BS, LANES), bf16), ((ROWS, LITE_NB), i32),
-                 ((ROWS,), i32))
+                 ((LITE.num_layers - 1, NUM_PAGES, BS, LANES), bf16),
+                 ((ROWS, LITE_NB), i32), ((ROWS,), i32), ((), i32))
     assert _has_kernel(c)
 
 
@@ -224,7 +260,10 @@ def test_moe_gmm_compiles(one_chip, rows, which):
 def test_deepseek_step_compiles_and_fits(one_chip, monkeypatch, step):
     """DeepSeek-V2-Lite's whole step programs, 9 layers at published
     widths and 64 slots: the latent kernel and the expert matmul are in,
-    and parameters plus the latent pool held twice fit one chip."""
+    and parameters, the latent pool and the output, counted apart, fit
+    one chip.  The decode window updates the scanned layers' stacked
+    latent pool in place (no one-layer slice of it, no write-back) and
+    aliases the donated pools from input to output."""
     monkeypatch.setattr(ops, "_default_interpret", lambda: False)
     params = jax.tree.map(
         lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
@@ -255,5 +294,10 @@ def test_deepseek_step_compiles_and_fits(one_chip, monkeypatch, step):
     E, D, F = LITE.num_experts, LITE.d_model, LITE.moe_d_ff
     assert not re.search(rf"= bf16\[{E},({D},{F}|{F},{D})\]", text)
     m = c.memory_analysis()
+    if step == "decode_window":
+        one = (f"bf16[1,{NUM_PAGES},{BS},{LANES}]",)
+        stacked = f"bf16[{LITE.num_layers - 1},{NUM_PAGES},{BS},{LANES}]"
+        assert _pool_copies(c, one, stacked) == []
+        assert m.alias_size_in_bytes >= _pool_bytes(cache)
     assert (m.argument_size_in_bytes + m.output_size_in_bytes
             + m.temp_size_in_bytes) < HBM_BYTES
